@@ -1,6 +1,6 @@
 """qwen3-1.7b [dense] — 28L d_model=2048 16H (GQA kv=8) d_ff=6144 vocab=151936.
 
-[hf:Qwen/Qwen3-8B; hf]. QK-RMSNorm on per-head query/key, GQA, head_dim=128.
+[hf:Qwen/Qwen3-1.7B; hf]. QK-RMSNorm on per-head query/key, GQA, head_dim=128.
 """
 from repro.configs.base import ModelConfig
 

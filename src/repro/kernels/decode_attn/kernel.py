@@ -32,11 +32,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x names the Mosaic params TPUCompilerParams; newer jax went
-# back to CompilerParams — resolve whichever this jax provides
-_COMPILER_PARAMS = getattr(pltpu, "TPUCompilerParams", None) \
-    or pltpu.CompilerParams
-
 DEFAULT_TB = 256
 NEG_INF = -1e30
 
@@ -150,7 +145,7 @@ def fused_decode_attention(q, k_packed, k_scale, k_zero,
             pltpu.VMEM((gq, 1), jnp.float32),     # running denom
             pltpu.VMEM((gq, hd), jnp.float32),    # accumulator
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(cur_len, q, k_packed, k_scale, k_zero, v_packed, v_scale, v_zero)

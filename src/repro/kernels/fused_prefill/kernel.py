@@ -43,11 +43,6 @@ from repro.kernels.decode_attn.kernel import (
     _expand_groups_cols, _expand_groups_rows, _unpack_cols, _unpack_rows,
 )
 
-# jax 0.4.x names the Mosaic params TPUCompilerParams; newer jax went
-# back to CompilerParams — resolve whichever this jax provides
-_COMPILER_PARAMS = getattr(pltpu, "TPUCompilerParams", None) \
-    or pltpu.CompilerParams
-
 DEFAULT_TB = 256
 NEG_INF = -1e30
 
@@ -146,7 +141,7 @@ def fused_chunk_prefill(q, k_packed, k_scale, k_zero,
             pltpu.VMEM((c, 1), jnp.float32),      # running denom
             pltpu.VMEM((c, hd), jnp.float32),     # accumulator
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(cur_len, q, k_packed, k_scale, k_zero,

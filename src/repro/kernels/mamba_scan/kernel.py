@@ -22,11 +22,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x names the Mosaic params TPUCompilerParams; newer jax went
-# back to CompilerParams — resolve whichever this jax provides
-_COMPILER_PARAMS = getattr(pltpu, "TPUCompilerParams", None) \
-    or pltpu.CompilerParams
-
 
 def _scan_kernel(dt_ref, x_ref, b_ref, c_ref, a_ref, h0_ref,
                  y_ref, hT_ref, h_scr, *, tc: int, dtile: int, n: int):
@@ -93,7 +88,7 @@ def selective_scan(dt, x, bs, cs, a, h0, *, tc: int = 64, dtile: int = 128,
             jax.ShapeDtypeStruct((bsz, d, n), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((dtile, n), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(dt, x, bs, cs, a, h0)

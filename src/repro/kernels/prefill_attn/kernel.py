@@ -17,11 +17,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# jax 0.4.x names the Mosaic params TPUCompilerParams; newer jax went
-# back to CompilerParams — resolve whichever this jax provides
-_COMPILER_PARAMS = getattr(pltpu, "TPUCompilerParams", None) \
-    or pltpu.CompilerParams
-
 NEG_INF = -1e30
 
 
@@ -85,7 +80,7 @@ def flash_attention(q, k, v, *, qb: int = 256, kb: int = 256,
             pltpu.VMEM((qb, 1), jnp.float32),
             pltpu.VMEM((qb, hd), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)

@@ -1,12 +1,17 @@
-"""Serving driver: AdaptCache end-to-end on a smoke model.
+"""Serving driver: AdaptCache end-to-end on a smoke or full-width model.
 
     PYTHONPATH=src python -m repro.launch.serve --arch adaptcache-8b \
         --policy adaptive --alpha 0.01 --rate 0.5 --duration 60 \
         [--train-steps 150] [--fit-estimator] [--replicas N] [--lanes K]
 
-Trains the smoke model on the recall task first (so compression has a
-measurable quality effect), optionally fits the paper's offline quality
-estimator, then serves a Poisson workload on the duplex-async event
+    PYTHONPATH=src python -m repro.launch.serve --arch qwen3-1.7b \
+        --full-width --policy kivi:0.25 --rate 2 --duration 6
+
+By default it trains the smoke variant of ``--arch`` on the recall task
+first (so compression has a measurable quality effect); ``--full-width``
+instead serves the arch at its published widths with weights drawn from
+``--seed`` and no training. It optionally fits the paper's offline
+quality estimator, then serves a Poisson workload on the duplex-async event
 engine (loads/prefills overlap decode, inserts and MCKP moves queue on
 write channels, ``--prefetch N`` enables speculative SSD->DRAM
 promotion; ``--serialized`` selects the legacy blocking loop) and prints
@@ -33,6 +38,8 @@ exact repeats recompute nothing. Both need ``--paged``.
 from __future__ import annotations
 
 import argparse
+import os
+import pathlib
 import sys
 
 import jax
@@ -53,9 +60,27 @@ from repro.training.optimizer import AdamWConfig, wsd_schedule
 from repro.training.train_step import init_train_state, make_train_step
 
 
+REPO_ROOT = pathlib.Path(__file__).resolve().parents[3]
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache and return its path.
+
+    ``JAX_COMPILATION_CACHE_DIR``, when set, is JAX's own setting and is
+    left alone. Otherwise the cache lives at ``<repo>/.jax_cache``: a
+    fixed path, because the path is part of the cache key."""
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not path:
+        path = str(REPO_ROOT / ".jax_cache")
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
+
+
 def train_smoke_model(cfg, steps: int = 150, seq: int = 192, batch: int = 8,
                       seed: int = 0):
     model = build_model(cfg)
+    if steps <= 0:
+        return model, jax.jit(model.init)(jax.random.key(seed))
     opt_cfg = AdamWConfig(lr=wsd_schedule(3e-3, steps // 10, steps // 2,
                                           steps // 3))
     state = init_train_state(model, jax.random.key(seed), opt_cfg)
@@ -70,7 +95,24 @@ def train_smoke_model(cfg, steps: int = 150, seq: int = 192, batch: int = 8,
     return model, state.params
 
 
-def main(argv=None) -> int:
+def load_runner(args) -> ModelRunner:
+    """The model that serves: ``--arch`` at its published widths with
+    seeded random weights (``--full-width``), or its trained smoke
+    variant."""
+    if args.full_width:
+        cfg = get_config(args.arch)
+        model = build_model(cfg)
+        params = jax.jit(model.init)(jax.random.key(args.seed))
+        print(f"{cfg.name} at published widths ({cfg.n_layers}L, d_model "
+              f"{cfg.d_model}, vocab {cfg.vocab_size}, {cfg.dtype}), "
+              f"weights from seed {args.seed}, untrained")
+    else:
+        model, params = train_smoke_model(get_config(args.arch, smoke=True),
+                                          args.train_steps)
+    return ModelRunner(model, params, capacity=1024)
+
+
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="adaptcache-8b")
     ap.add_argument("--policy", default="adaptive",
@@ -86,6 +128,11 @@ def main(argv=None) -> int:
     ap.add_argument("--duration", type=float, default=90.0)
     ap.add_argument("--contexts-per-task", type=int, default=4)
     ap.add_argument("--train-steps", type=int, default=150)
+    ap.add_argument("--full-width", action="store_true",
+                    help="serve --arch at its published widths with "
+                         "weights drawn from --seed, untrained (ignores "
+                         "--train-steps); default serves the trained "
+                         "smoke variant")
     ap.add_argument("--fit-estimator", action="store_true")
     ap.add_argument("--dram-entries", type=float, default=3.0)
     ap.add_argument("--ssd-entries", type=float, default=12.0)
@@ -188,12 +235,14 @@ def main(argv=None) -> int:
                  "--chunk-tokens")
     if args.slo and not args.tenants:
         ap.error("--slo overrides tenant TTFT SLOs: add --tenants")
+    return args
 
-    smoke_cfg = get_config(args.arch, smoke=True)
+
+def serve(args, runner: ModelRunner):
+    """Build the workload and the engine and serve it. Returns
+    ``(rig, requests, results, summary)``."""
     full_cfg = get_config(args.arch)
-    model, params = train_smoke_model(smoke_cfg, args.train_steps)
-    runner = ModelRunner(model, params, capacity=1024)
-
+    vocab = runner.model.cfg.vocab_size
     rng = np.random.RandomState(args.seed)
     tenants = None
     if args.tenants:
@@ -203,7 +252,7 @@ def main(argv=None) -> int:
             tenants = [_dc.replace(t, ttft_slo_s=args.slo)
                        for t in tenants]
         contexts, requests = make_tenant_workload(
-            rng, smoke_cfg.vocab_size,
+            rng, vocab,
             n_docs_per_tenant=args.contexts_per_task,
             tenants=tenants, base_rate_hz=args.rate,
             duration_s=args.duration)
@@ -211,8 +260,8 @@ def main(argv=None) -> int:
               + ", ".join(f"{t.name}(tier={t.tier}, "
                           f"quota={t.quota_tokens}tok)" for t in tenants))
     else:
-        contexts = make_contexts(rng, smoke_cfg.vocab_size,
-                                 args.contexts_per_task, n_probes=3)
+        contexts = make_contexts(rng, vocab, args.contexts_per_task,
+                                 n_probes=3)
         requests = poisson_requests(rng, contexts, args.rate, args.duration)
     print(f"{len(contexts)} contexts, {len(requests)} requests")
 
@@ -271,7 +320,15 @@ def main(argv=None) -> int:
                                    if args.readahead_pages
                                    and not args.serialized else None),
                   selector_stats=rig.controller.selector.stats)
-    print("\n=== serving summary ===")
+    return rig, requests, results, s
+
+
+def main(argv=None) -> int:
+    args = parse_args(argv)
+    enable_compile_cache()
+    rig, _, _, s = serve(args, load_runner(args))
+    print("\n=== serving summary (times simulated by TimeModel on "
+          "A100 constants) ===")
     for k, v in s.items():
         print(f"  {k:16s} {v:.4f}" if isinstance(v, float) else
               f"  {k:16s} {v}")

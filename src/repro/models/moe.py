@@ -78,10 +78,7 @@ def moe_fwd_ep(p: Params, cfg: ModelConfig, x: jax.Array,
     Per-layer collective cost: psum of (t_loc, d) activations (+ FSDP
     weight all-gathers), matching dense-TP blocks.
     """
-    try:                                 # jax >= 0.5 top-level export
-        from jax import shard_map
-    except ImportError:                  # jax 0.4.x
-        from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.launch import sharding as shlib
 
@@ -153,18 +150,14 @@ def moe_fwd_ep(p: Params, cfg: ModelConfig, x: jax.Array,
         return out.reshape(x_blk.shape), aux
 
     d_spec = d_ax
-    sm_kw = dict(
-        mesh=mesh,
+    wrapped = shard_map(
+        body, mesh=mesh, check_vma=False,
         in_specs=(P(d_spec, None, None),        # x: batch over data
                   P(None, None),                # router: replicated
                   P(m_ax, None, None),          # wi_gate (E, d, ff): EP only
                   P(m_ax, None, None),          # wi_up
                   P(m_ax, None, None)),         # wo (E, ff, d)
         out_specs=(P(d_spec, None, None), P()))
-    try:                                 # jax >= 0.7: check_vma
-        wrapped = shard_map(body, check_vma=False, **sm_kw)
-    except TypeError:                    # jax 0.4.x: check_rep
-        wrapped = shard_map(body, check_rep=False, **sm_kw)
     out, aux = wrapped(
         x, p["router"], p["experts"]["wi_gate"], p["experts"]["wi_up"],
         p["experts"]["wo"])

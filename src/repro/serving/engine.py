@@ -127,6 +127,7 @@ import math
 import os
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
 
 from repro.configs.base import LayerKind
@@ -486,11 +487,14 @@ class ServingEngine:
             if topo is None or topo.shared_dram:
                 return fast_tier
             return topo.dram_for(rep.idx)
+        # replica r runs on device r mod n: one replica per chip
+        devices = jax.devices()
         replicas = [
             _Replica(i, ContinuousBatcher(self.runner.model,
                                           self.runner.params, self.tm,
                                           n_slots=self.n_lanes,
-                                          capacity=self.runner.capacity))
+                                          capacity=self.runner.capacity,
+                                          device=devices[i % len(devices)]))
             for i in range(self.n_replicas)]
         if self.chunk_tokens > 0:
             # unified compute: decode ticks and prefill chunks share ONE

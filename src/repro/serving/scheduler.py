@@ -98,14 +98,22 @@ def _shared_decode(model: Model):
 
 
 class ContinuousBatcher:
+    """``device`` (default: JAX's default device) holds this batcher's
+    params and lane cache, so every decode tick and lane write runs
+    there — one engine replica per chip."""
+
     def __init__(self, model: Model, params, time_model: TimeModel,
-                 n_slots: int = 4, capacity: int = 1024):
+                 n_slots: int = 4, capacity: int = 1024, device=None):
         self.model = model
-        self.params = params
         self.tm = time_model
         self.n_slots = n_slots
         self.capacity = capacity
-        self.cache = model.init_cache(batch=n_slots, capacity=capacity)
+        self.device = device or jax.devices()[0]
+        self.params = jax.device_put(params, self.device)
+        with jax.default_device(self.device):
+            self.cache = jax.device_put(
+                model.init_cache(batch=n_slots, capacity=capacity),
+                self.device)
         self.slots = [SlotState() for _ in range(n_slots)]
         self._decode = _shared_decode(model)
 
@@ -126,15 +134,17 @@ class ContinuousBatcher:
         for i, kind, (sect, j, g) in _layer_cache_refs(self.cache, cfg):
             blk = self.cache[sect][j]
 
+            # stored entries are float32; the lane cache holds the
+            # model dtype, so cast before the scatter
             def put(d, name, val):
-                val = jnp.asarray(val)
+                val = jnp.asarray(val, d[name].dtype)
                 if g is not None:
                     d[name] = d[name].at[g, lane, :val.shape[0]].set(val)
                 else:
                     d[name] = d[name].at[lane, :val.shape[0]].set(val)
 
             def put_full(d, name, val):
-                val = jnp.asarray(val)
+                val = jnp.asarray(val, d[name].dtype)
                 if g is not None:
                     d[name] = d[name].at[g, lane].set(val)
                 else:

@@ -5,15 +5,11 @@ device spec abstraction so the same policy runs with TPU-host constants
 (DESIGN.md §4). Realism requirements honored:
 
   * DRAMTier holds real numpy buffers (bytes are resident);
-  * SSDTier serializes entries to real files (codec-framed, CRC-checked)
+  * SSDTier serializes entries to real files (zstd-framed, CRC-checked)
     under a spool directory — bytes genuinely leave memory;
   * delay accounting is a calibrated model (default: the paper's 1 GB/s
     disk; DRAM->device 16 GB/s PCIe-class) so benchmark numbers are
     host-independent, while ``measure=True`` uses actual wall-clock I/O.
-
-``zstandard`` is an optional dependency: when absent, SSD frames fall
-back to ``zlib``. The codec is recorded in each entry's header so frames
-are self-describing regardless of which codec wrote them.
 """
 from __future__ import annotations
 
@@ -26,11 +22,7 @@ import zlib
 from typing import Any, Dict, Iterable, Optional, Tuple
 
 import numpy as np
-
-try:                                    # optional transport codec
-    import zstandard
-except ImportError:                     # pragma: no cover - env dependent
-    zstandard = None
+import zstandard
 
 from repro.core.compression.base import CompressedEntry
 
@@ -138,71 +130,36 @@ class DRAMTier(Tier):
 
 
 _MAGIC = b"ADKV"
-_HEADER = struct.Struct("<BIQ")          # codec id, CRC32(raw), raw length
-CODEC_ZLIB = 0
-CODEC_ZSTD = 1
-
-
-def _default_codec() -> int:
-    return CODEC_ZSTD if zstandard is not None else CODEC_ZLIB
+_HEADER = struct.Struct("<IQ")           # CRC32(raw), raw length
 
 
 class SSDTier(Tier):
-    """File-backed tier: one codec-framed, CRC-checked file per entry.
-
-    Frames are zstd when ``zstandard`` is importable, zlib otherwise; the
-    codec id in the header makes every frame self-describing.
-    """
+    """File-backed tier: one zstd-framed, CRC-checked file per entry."""
 
     def __init__(self, spec: DeviceSpec = PAPER_SSD,
                  root: Optional[str] = None, measure: bool = False,
-                 codec: Optional[int] = None, name: Optional[str] = None):
+                 name: Optional[str] = None):
         super().__init__(spec, name=name)
         self.root = root or tempfile.mkdtemp(prefix="adaptcache_ssd_")
         self.measure = measure
-        self.codec = _default_codec() if codec is None else codec
-        if self.codec == CODEC_ZSTD and zstandard is None:
-            raise RuntimeError("zstd codec requested but zstandard is "
-                               "not installed")
-        if zstandard is not None:
-            self._cctx = zstandard.ZstdCompressor(level=1)
-            self._dctx = zstandard.ZstdDecompressor()
+        self._cctx = zstandard.ZstdCompressor(level=1)
+        self._dctx = zstandard.ZstdDecompressor()
         os.makedirs(self.root, exist_ok=True)
 
     def _path(self, key: str) -> str:
         return os.path.join(self.root, key.replace("/", "_") + ".kv")
 
-    def _frame(self, raw: bytes) -> bytes:
-        if self.codec == CODEC_ZSTD:
-            return self._cctx.compress(raw)
-        return zlib.compress(raw, 1)
-
-    def _unframe(self, codec: int, data: bytes, orig_len: int) -> bytes:
-        if codec == CODEC_ZSTD:
-            if zstandard is None:
-                raise IOError("entry framed with zstd but zstandard is "
-                              "not installed")
-            return self._dctx.decompress(data, max_output_size=orig_len)
-        if codec == CODEC_ZLIB:
-            d = zlib.decompressobj()
-            raw = d.decompress(data, orig_len)   # bound expansion
-            if len(raw) != orig_len or d.unconsumed_tail:
-                raise IOError("zlib frame length mismatch — corrupt SSD "
-                              "page")
-            return raw
-        raise IOError(f"unknown SSD frame codec id {codec}")
-
     def put(self, key: str, entry: CompressedEntry) -> int:
         if key in self._meta:
             self.evict(key)
         raw = entry.tobytes()
-        framed = self._frame(raw)
+        framed = self._cctx.compress(raw)
         crc = zlib.crc32(raw)
         path = self._path(key)
         tmp = path + ".tmp"
         with open(tmp, "wb") as f:
             f.write(_MAGIC)
-            f.write(_HEADER.pack(self.codec, crc, len(raw)))
+            f.write(_HEADER.pack(crc, len(raw)))
             f.write(framed)
         os.replace(tmp, path)                       # atomic
         # capacity accounting uses the LOGICAL entry size (policy view);
@@ -223,8 +180,8 @@ class SSDTier(Tier):
         t0 = time.perf_counter()  # simcheck: ignore[wallclock]
         with open(info["path"], "rb") as f:
             assert f.read(4) == _MAGIC, f"corrupt frame for {key}"
-            codec, crc, orig_len = _HEADER.unpack(f.read(_HEADER.size))
-            raw = self._unframe(codec, f.read(), orig_len)
+            crc, orig_len = _HEADER.unpack(f.read(_HEADER.size))
+            raw = self._dctx.decompress(f.read(), max_output_size=orig_len)
         if zlib.crc32(raw) != crc:
             raise IOError(f"CRC mismatch for entry {key} — corrupt SSD page")
         entry = CompressedEntry.frombytes(raw, info["method"], info["rate"],

@@ -109,9 +109,7 @@ def test_quantize_roundtrip_error_bounded_per_group():
     most half a step, where the step is the GROUP's (max-min)/(2^b-1) —
     the bound that makes in-VREG dequant numerically interchangeable
     with the standalone pass."""
-    hypothesis = pytest.importorskip("hypothesis")
-    given, settings = hypothesis.given, hypothesis.settings
-    st = pytest.importorskip("hypothesis.strategies")
+    from hypothesis import given, settings, strategies as st
 
     @settings(deadline=None, max_examples=40)
     @given(st.integers(0, 2 ** 31 - 1), st.sampled_from([2, 4, 8]),

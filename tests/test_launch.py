@@ -1,6 +1,7 @@
 """Launch machinery: dry-run cell end-to-end in a subprocess (forced host
 devices), roofline math, elastic checkpoint restore across mesh sizes."""
 import json
+import os
 import subprocess
 import sys
 import textwrap
@@ -54,8 +55,8 @@ _DRYRUN_SNIPPET = textwrap.dedent("""
     from repro.launch.mesh import make_production_mesh
     # shrink the mesh for CI speed: monkeypatch the factory
     import repro.launch.mesh as mesh_mod
-    mesh_mod.make_production_mesh = lambda multi_pod=False: jax.make_mesh(
-        (4, 4), ("data", "model"))
+    mesh_mod.make_production_mesh = lambda multi_pod=False: (
+        mesh_mod.make_mesh((4, 4), ("data", "model")))
     dr.make_production_mesh = mesh_mod.make_production_mesh
     res = dr.run_cell("smollm-135m", "decode_32k", multi_pod=False,
                       verbose=False)
@@ -69,8 +70,8 @@ def test_dryrun_cell_subprocess():
     """A full dry-run cell (lower+compile+roofline) on a 4x4 mesh."""
     r = subprocess.run([sys.executable, "-c", _DRYRUN_SNIPPET],
                        capture_output=True, text=True, timeout=560,
-                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                            "HOME": "/root"})
+                       env={**os.environ, "XLA_FLAGS": "",
+                            "PYTHONPATH": "src"})
     assert r.returncode == 0, r.stderr[-3000:]
     out = json.loads(r.stdout.strip().splitlines()[-1])
     assert out["status"] == "ok", out
@@ -84,6 +85,7 @@ _ELASTIC_SNIPPET = textwrap.dedent("""
     from repro.configs import get_config
     from repro.models import build_model
     from repro.launch import specs as sp
+    from repro.launch.mesh import make_mesh
     from repro.training.checkpoint import CheckpointManager
     from repro.training.optimizer import AdamWConfig
     from repro.training.train_step import init_train_state
@@ -91,7 +93,7 @@ _ELASTIC_SNIPPET = textwrap.dedent("""
     cfg = get_config("qwen3-1.7b", smoke=True)
     model = build_model(cfg)
     opt = AdamWConfig(lr=1e-3)
-    mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+    mesh_a = make_mesh((4, 2), ("data", "model"))
     sh_a = sp.train_state_shardings(
         jax.eval_shape(lambda: init_train_state(model, jax.random.key(0),
                                                 opt)), mesh_a)
@@ -102,7 +104,7 @@ _ELASTIC_SNIPPET = textwrap.dedent("""
     cm = CheckpointManager(d, async_write=False)
     cm.save(1, state, extra={"step": 1})
     # elastic restore: 8 devices -> 4 (downscale), new mesh (2, 2)
-    mesh_b = jax.make_mesh((2, 2), ("data", "model"))
+    mesh_b = make_mesh((2, 2), ("data", "model"))
     sh_b = sp.train_state_shardings(
         jax.eval_shape(lambda: init_train_state(model, jax.random.key(0),
                                                 opt)), mesh_b)
@@ -120,8 +122,8 @@ def test_elastic_checkpoint_restore_subprocess():
     """Checkpoint written on a (4,2) mesh restores bit-exactly onto (2,2)."""
     r = subprocess.run([sys.executable, "-c", _ELASTIC_SNIPPET],
                        capture_output=True, text=True, timeout=560,
-                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                            "HOME": "/root"})
+                       env={**os.environ, "XLA_FLAGS": "",
+                            "PYTHONPATH": "src"})
     assert r.returncode == 0, r.stderr[-3000:]
     out = json.loads(r.stdout.strip().splitlines()[-1])
     assert out["equal"] and out["step"] == 1, out

@@ -172,20 +172,18 @@ def test_enforce_terminates_within_capacity(tmp_path):
 
 def test_ssd_roundtrip_preserves_bytes(tmp_path):
     from repro.core.compression.base import CompressedEntry
-    from repro.storage.tier import CODEC_ZLIB, SSDTier, DeviceSpec
+    from repro.storage.tier import SSDTier, DeviceSpec
     arrays = {"k": RNG.randn(3, 17, 5).astype(np.float32),
               "v": RNG.randn(3, 17, 5).astype(np.float32),
               "positions": np.arange(17, dtype=np.int32)}
-    for codec, sub in ((None, "default"), (CODEC_ZLIB, "zlib")):
-        tier = SSDTier(DeviceSpec("ssd", 1 << 30, 1e9, 1e9),
-                       root=str(tmp_path / sub), codec=codec)
-        entry = CompressedEntry("none", 1.0, arrays, {})
-        tier.put("a", entry)
-        back = tier.get("a")
-        assert back.method == "none" and back.rate == 1.0
-        for name, arr in arrays.items():
-            np.testing.assert_array_equal(back.arrays[name], arr)
-            assert back.arrays[name].dtype == arr.dtype
+    tier = SSDTier(DeviceSpec("ssd", 1 << 30, 1e9, 1e9), root=str(tmp_path))
+    entry = CompressedEntry("none", 1.0, arrays, {})
+    tier.put("a", entry)
+    back = tier.get("a")
+    assert back.method == "none" and back.rate == 1.0
+    for name, arr in arrays.items():
+        np.testing.assert_array_equal(back.arrays[name], arr)
+        assert back.arrays[name].dtype == arr.dtype
 
 
 def test_ssd_evict_tolerates_unlinked_file(tmp_path):

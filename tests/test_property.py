@@ -1,8 +1,6 @@
 """Hypothesis property tests on system invariants."""
 import numpy as np
 import pytest
-
-pytest.importorskip("hypothesis")
 from hypothesis import given, settings, strategies as st
 
 from repro.core.compression import (

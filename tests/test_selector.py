@@ -9,6 +9,7 @@ import heapq
 
 import numpy as np
 import pytest
+from hypothesis import given, settings, strategies as st
 
 from repro.core.compression import default_registry
 from repro.core.controller import AdaptCacheController
@@ -23,12 +24,6 @@ from repro.core.selector import (
 from repro.serving.sanitizer import SanitizerError, SimSanitizer
 from repro.storage.tier import DRAMTier, DeviceSpec, SSDTier
 from repro.storage.topology import StorageTopology
-
-try:
-    from hypothesis import given, settings, strategies as st
-    HAVE_HYPOTHESIS = True
-except ImportError:
-    HAVE_HYPOTHESIS = False
 
 
 def make_kv(rng, T=128, L=2, F=64):
@@ -178,21 +173,20 @@ def test_randomized_equivalence_fixed_policy(tmp_path, spec):
     assert logs["indexed"] == logs["scan"]
 
 
-if HAVE_HYPOTHESIS:
-    @given(seed=st.integers(0, 10_000), paged=st.booleans(),
-           split=st.booleans(), n_ops=st.integers(20, 60))
-    @settings(max_examples=15, deadline=None)
-    def test_equivalence_property(tmp_path_factory, seed, paged, split,
-                                  n_ops):
-        """Property form of the equivalence harness: any randomized
-        history (topology on/off, runs on/off) yields identical move
-        sequences and final placements."""
-        topo = (StorageTopology(replicas=2, shared_dram=False)
-                if split else None)
-        ops = gen_ops(np.random.RandomState(seed), n_ops=n_ops,
-                      paged=paged, replicas=2 if split else 1)
-        assert_equivalent(ops, tmp_path_factory.mktemp("prop"),
-                          topology=topo)
+@given(seed=st.integers(0, 10_000), paged=st.booleans(),
+       split=st.booleans(), n_ops=st.integers(20, 60))
+@settings(max_examples=15, deadline=None)
+def test_equivalence_property(tmp_path_factory, seed, paged, split,
+                              n_ops):
+    """Property form of the equivalence harness: any randomized
+    history (topology on/off, runs on/off) yields identical move
+    sequences and final placements."""
+    topo = (StorageTopology(replicas=2, shared_dram=False)
+            if split else None)
+    ops = gen_ops(np.random.RandomState(seed), n_ops=n_ops,
+                  paged=paged, replicas=2 if split else 1)
+    assert_equivalent(ops, tmp_path_factory.mktemp("prop"),
+                      topology=topo)
 
 
 # -- per-tier entry index ----------------------------------------------------

@@ -1,6 +1,7 @@
 """Sharding rules + distributed execution correctness (subprocess with
 forced host devices where >1 device is needed)."""
 import json
+import os
 import subprocess
 import sys
 import textwrap
@@ -12,6 +13,7 @@ import pytest
 
 from repro.configs import ASSIGNED, get_config
 from repro.launch import specs as sp
+from repro.launch.mesh import make_mesh
 from repro.launch.sharding import constrain, use_mesh
 from repro.models import build_model
 
@@ -26,7 +28,7 @@ def test_constrain_noop_without_mesh():
 
 
 def test_guard_drops_nondivisible_axes():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     s = sp.sharding(mesh, (7, 16), "data", "model")
     assert s.spec == jax.sharding.PartitionSpec(None, None) or \
         mesh.shape["data"] == 1      # trivially fine on 1x1
@@ -37,7 +39,7 @@ def test_param_shardings_cover_all_leaves(name):
     cfg = get_config(name, smoke=True)
     model = build_model(cfg)
     shapes = model.init_shapes()
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     sh = sp.param_shardings(shapes, mesh)
     n_leaves = len(jax.tree.leaves(shapes))
     n_sh = len(jax.tree.leaves(sh, is_leaf=lambda x: isinstance(
@@ -53,6 +55,7 @@ _DISTRIBUTED_SNIPPET = textwrap.dedent("""
     from repro.configs import get_config
     from repro.models import build_model
     from repro.launch import specs as sp
+    from repro.launch.mesh import make_mesh
     from repro.launch.sharding import use_mesh
     from repro.training.optimizer import AdamWConfig
     from repro.training.train_step import init_train_state, make_train_step
@@ -72,7 +75,7 @@ _DISTRIBUTED_SNIPPET = textwrap.dedent("""
     _, m0 = jax.jit(step)(state0, batch)
 
     # 4x2 mesh distributed
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = make_mesh((4, 2), ("data", "model"))
     state_sh = sp.train_state_shardings(
         jax.eval_shape(lambda: init_train_state(model, jax.random.key(0),
                                                 opt)), mesh)
@@ -93,8 +96,8 @@ def test_distributed_matches_single_device():
     """4x2-mesh sharded train step == single-device step (same loss)."""
     r = subprocess.run([sys.executable, "-c", _DISTRIBUTED_SNIPPET],
                        capture_output=True, text=True, timeout=560,
-                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                            "HOME": "/root"})
+                       env={**os.environ, "XLA_FLAGS": "",
+                            "PYTHONPATH": "src"})
     assert r.returncode == 0, r.stderr[-3000:]
     out = json.loads(r.stdout.strip().splitlines()[-1])
     assert abs(out["loss0"] - out["loss1"]) < 2e-3, out
@@ -107,13 +110,14 @@ _EP_MOE_SNIPPET = textwrap.dedent("""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.configs import get_config
     from repro.models import moe as M
+    from repro.launch.mesh import make_mesh
     from repro.launch.sharding import use_mesh
 
     cfg = get_config("olmoe-1b-7b", smoke=True)
     p = M.init_moe(jax.random.key(0), cfg, jnp.float32)
     x = jax.random.normal(jax.random.key(1), (4, 16, cfg.d_model)) * 0.5
     out_plain, _ = M.moe_fwd(p, cfg, x, dropless=True)
-    mesh = jax.make_mesh((2, 4), ("data", "model"))
+    mesh = make_mesh((2, 4), ("data", "model"))
     xs = jax.device_put(x, NamedSharding(mesh, P("data", None, None)))
     def f(p, x):
         with use_mesh(mesh):
@@ -130,8 +134,8 @@ def test_ep_moe_matches_plain():
     """shard_map expert-parallel MoE == single-device reference."""
     r = subprocess.run([sys.executable, "-c", _EP_MOE_SNIPPET],
                        capture_output=True, text=True, timeout=560,
-                       env={"PYTHONPATH": "src", "PATH": "/usr/bin:/bin",
-                            "HOME": "/root"})
+                       env={**os.environ, "XLA_FLAGS": "",
+                            "PYTHONPATH": "src"})
     assert r.returncode == 0, r.stderr[-3000:]
     out = json.loads(r.stdout.strip().splitlines()[-1])
     assert out["rel"] < 1e-4, out
@@ -141,7 +145,7 @@ def test_cache_shardings_decode_vs_long():
     import os
     cfg = get_config("jamba-1.5-large-398b", smoke=True)
     model = build_model(cfg)
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cache_shapes = jax.eval_shape(lambda: model.init_cache(2, 64))
     sh_dec = sp.cache_shardings(cache_shapes, mesh, long_context=False)
     sh_long = sp.cache_shardings(cache_shapes, mesh, long_context=True)

@@ -156,9 +156,7 @@ def test_quota_and_ledger_hypothesis_properties(tmp_path):
     """For ANY interleaving of tenanted inserts and fetches: (a) each
     tier's ledger buckets recount exactly and sum to used_bytes, and
     (b) no quota'd tenant ever exceeds its quota after an insert."""
-    hypothesis = pytest.importorskip("hypothesis")
-    given, settings = hypothesis.given, hypothesis.settings
-    st = pytest.importorskip("hypothesis.strategies")
+    from hypothesis import given, settings, strategies as st
 
     quota = 3 * sum(a.nbytes for a in make_kv(T=64).values())
     quotas = {"a": quota, "b": 2 * quota}

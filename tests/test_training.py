@@ -179,11 +179,12 @@ def test_fault_tolerance_primitives():
 def test_compressed_psum_error_feedback():
     """int8 gradient compression: quantization error is captured in the
     EF residual so (reduced + residual) reconstructs the exact sum."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
+    from repro.launch.mesh import make_mesh
     from repro.training.train_step import compressed_psum
 
-    mesh = jax.make_mesh((1,), ("d",))
+    mesh = make_mesh((1,), ("d",))
     x = jnp.asarray(np.random.RandomState(0).randn(64).astype(np.float32))
 
     def f(x):
