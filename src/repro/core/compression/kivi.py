@@ -17,6 +17,7 @@ from repro.core.compression.base import (
     CompressedEntry, CompressionMethod, KVData, kv_nbytes,
 )
 from repro.kernels.kivi import ops as kivi_ops
+from repro.runtime.spans import span
 
 BITS_LADDER = (8, 4, 2)
 
@@ -86,10 +87,13 @@ class KIVICompression(CompressionMethod):
             if pad:
                 widths = [(0, pad), (0, 0)] if axis == 0 else [(0, 0), (0, pad)]
                 mat = np.pad(mat, widths)
-            qt = kivi_ops.quantize(jnp.asarray(mat), bits, g, axis)
-            arrays[f"{name}.packed"] = np.asarray(qt.packed)
-            arrays[f"{name}.scale"] = np.asarray(qt.scale)
-            arrays[f"{name}.zero"] = np.asarray(qt.zero)
+            rows, cols = mat.shape if axis == 0 else mat.shape[::-1]
+            with span("kivi_quantize", rows=rows, cols=cols, bits=bits,
+                      group=g):
+                qt = kivi_ops.quantize(jnp.asarray(mat), bits, g, axis)
+                arrays[f"{name}.packed"] = np.asarray(qt.packed)
+                arrays[f"{name}.scale"] = np.asarray(qt.scale)
+                arrays[f"{name}.zero"] = np.asarray(qt.zero)
             meta["group"][name] = g
             meta["shape"][name] = a.shape
             meta["axis"][name] = axis
@@ -104,9 +108,7 @@ class KIVICompression(CompressionMethod):
             axis = entry.meta["axis"][name]
             g = entry.meta["group"][name]
             bits = entry.meta["bits"]
-            packed = jnp.asarray(entry.arrays[f"{name}.packed"])
-            scale = jnp.asarray(entry.arrays[f"{name}.scale"])
-            zero = jnp.asarray(entry.arrays[f"{name}.zero"])
+            packed = entry.arrays[f"{name}.packed"]
             rows = int(np.prod(shape[:-1], dtype=np.int64))
             f = shape[-1]
             g = _round_group(g, bits)
@@ -115,8 +117,15 @@ class KIVICompression(CompressionMethod):
                 padded_dim = -(-rows // g) * g
             else:
                 padded_dim = -(-f // g) * g
-            qt = Quantized(packed, scale, zero, bits, g, axis, padded_dim)
-            mat = np.asarray(kivi_ops.dequantize(qt))
+            # the kernel's (rows, cols) of unpacked codes
+            p_rows, p_cols = packed.shape if axis == 0 else packed.shape[::-1]
+            with span("kivi_dequantize", rows=p_rows * (8 // bits),
+                      cols=p_cols, bits=bits, group=g):
+                qt = Quantized(jnp.asarray(packed),
+                               jnp.asarray(entry.arrays[f"{name}.scale"]),
+                               jnp.asarray(entry.arrays[f"{name}.zero"]),
+                               bits, g, axis, padded_dim)
+                mat = np.asarray(kivi_ops.dequantize(qt))
             mat = mat[:rows, :f]                     # strip padding
             out[name] = mat.reshape(shape).astype(entry.meta["dtype"][name])
         if "positions" in entry.arrays:

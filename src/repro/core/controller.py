@@ -60,6 +60,7 @@ from repro.core.estimator import (
 from repro.core.executor import Executor
 from repro.core.policy import AdaptivePolicy, BasePolicy, Move, Placement
 from repro.core.selector import make_selector
+from repro.runtime.spans import span
 from repro.storage.tier import Tier
 from repro.storage.topology import StorageTopology
 
@@ -212,73 +213,78 @@ class AdaptCacheController:
                transfers: Optional[List[Transfer]] = None,
                replica: Optional[int] = None,
                tenant: Optional[str] = None) -> Placement:
-        now = self.clock() if now is None else now
-        old = self.meta.get(key)
-        if old is not None and old.tier:
-            return Placement(old.tier, old.method, old.rate)
-        if old is not None:
-            # Re-insert after eviction: the policy's utility ranking runs
-            # on hits/last_hit history, so merge into the surviving meta
-            # instead of rebuilding it (only content-derived features and
-            # the creation stamp refresh).
-            meta = old
-            meta.task_type = task_type
-            meta.n_tokens = kv_num_tokens(kv)
-            meta.orig_bytes = kv_nbytes(kv)
-            meta.redundancy = redundancy_feature(kv)
-            meta.created_at = now
-            meta.home_replica = replica
-            meta.tenant = tenant
-        else:
-            meta = EntryMeta(key=key, task_type=task_type,
-                             n_tokens=kv_num_tokens(kv),
-                             orig_bytes=kv_nbytes(kv),
-                             redundancy=redundancy_feature(kv),
-                             created_at=now, home_replica=replica,
-                             tenant=tenant)
-        placement = self.policy.admit(meta, kv)
-        self.executor.store(meta, kv, placement)
-        self.meta[key] = meta
-        if not self.freq.seen(key):      # keep the EWMA of returning keys
-            self.freq.on_insert(key, now)
-        self.counters["inserts"] += 1
-        self.selector.touch(key, now)
-        if transfers is not None:
-            transfers.append(Transfer(key, "insert", meta.tier, meta.nbytes))
-        # quota BEFORE capacity: an over-quota tenant's insert storm
-        # sheds its own coldest entries first, which usually also fixes
-        # the tier overflow — other tenants' hot sets survive
-        self._enforce_quota(tenant, now)
-        self._enforce(placement.tier, now, transfers=transfers)
-        return placement
+        with span("insert"):
+            now = self.clock() if now is None else now
+            old = self.meta.get(key)
+            if old is not None and old.tier:
+                return Placement(old.tier, old.method, old.rate)
+            if old is not None:
+                # Re-insert after eviction: the policy's utility ranking
+                # runs on hits/last_hit history, so merge into the
+                # surviving meta instead of rebuilding it (only
+                # content-derived features and the creation stamp refresh).
+                meta = old
+                meta.task_type = task_type
+                meta.n_tokens = kv_num_tokens(kv)
+                meta.orig_bytes = kv_nbytes(kv)
+                meta.redundancy = redundancy_feature(kv)
+                meta.created_at = now
+                meta.home_replica = replica
+                meta.tenant = tenant
+            else:
+                meta = EntryMeta(key=key, task_type=task_type,
+                                 n_tokens=kv_num_tokens(kv),
+                                 orig_bytes=kv_nbytes(kv),
+                                 redundancy=redundancy_feature(kv),
+                                 created_at=now, home_replica=replica,
+                                 tenant=tenant)
+            placement = self.policy.admit(meta, kv)
+            self.executor.store(meta, kv, placement)
+            self.meta[key] = meta
+            if not self.freq.seen(key):      # keep the EWMA of returning keys
+                self.freq.on_insert(key, now)
+            self.counters["inserts"] += 1
+            self.selector.touch(key, now)
+            if transfers is not None:
+                transfers.append(Transfer(key, "insert", meta.tier,
+                                          meta.nbytes))
+            # quota BEFORE capacity: an over-quota tenant's insert storm
+            # sheds its own coldest entries first, which usually also
+            # fixes the tier overflow — other tenants' hot sets survive
+            self._enforce_quota(tenant, now)
+            self._enforce(placement.tier, now, transfers=transfers)
+            return placement
 
     def fetch(self, key: str, now: Optional[float] = None,
               replica: Optional[int] = None) -> Optional[FetchResult]:
-        now = self.clock() if now is None else now
-        meta = self.meta.get(key)
-        if meta is None or meta.tier is None:
-            self.counters["misses"] += 1
-            return None
-        tier = self.tiers[meta.tier]
-        kv, entry = self.executor.fetch(meta)
-        load = tier.load_delay_s(meta.nbytes)
-        dec = self.delay_profile.decompress_delay_s(meta.method, meta.nbytes)
-        # cross-replica hit: the bytes live in a sibling replica's DRAM —
-        # the fetch pays the owner's read path PLUS the replica link
-        remote = (self.topology is not None
-                  and not self.topology.is_local_hit(meta.tier, replica))
-        xlink = self.topology.cross_delay_s(meta.nbytes) if remote else 0.0
-        meta.hits += 1
-        meta.last_hit = now
-        self.freq.on_hit(key, now)
-        self.selector.touch(key, now)
-        self.counters["hits"] += 1
-        self.counters[f"hit_{meta.tier}"] += 1
-        if remote:
-            self.counters["hit_remote"] += 1
-        return FetchResult(kv, meta.tier, meta.method, meta.rate,
-                           load, dec, meta.nbytes, remote=remote,
-                           xlink_delay_s=xlink, orig_nbytes=meta.orig_bytes)
+        with span("page_fetch"):
+            now = self.clock() if now is None else now
+            meta = self.meta.get(key)
+            if meta is None or meta.tier is None:
+                self.counters["misses"] += 1
+                return None
+            tier = self.tiers[meta.tier]
+            kv, entry = self.executor.fetch(meta)
+            load = tier.load_delay_s(meta.nbytes)
+            dec = self.delay_profile.decompress_delay_s(meta.method,
+                                                        meta.nbytes)
+            # cross-replica hit: the bytes live in a sibling replica's
+            # DRAM — the fetch pays the owner's read path PLUS the link
+            remote = (self.topology is not None
+                      and not self.topology.is_local_hit(meta.tier, replica))
+            xlink = self.topology.cross_delay_s(meta.nbytes) if remote else 0.0
+            meta.hits += 1
+            meta.last_hit = now
+            self.freq.on_hit(key, now)
+            self.selector.touch(key, now)
+            self.counters["hits"] += 1
+            self.counters[f"hit_{meta.tier}"] += 1
+            if remote:
+                self.counters["hit_remote"] += 1
+            return FetchResult(kv, meta.tier, meta.method, meta.rate,
+                               load, dec, meta.nbytes, remote=remote,
+                               xlink_delay_s=xlink,
+                               orig_nbytes=meta.orig_bytes)
 
     def note_page_run(self, n_hit: int, n_pages: int,
                       run_key: Optional[str] = None,

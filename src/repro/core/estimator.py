@@ -15,7 +15,6 @@ from __future__ import annotations
 import collections
 import dataclasses
 import json
-import time
 from typing import Callable, Dict, FrozenSet, List, Optional, Sequence, Tuple
 
 import numpy as np
@@ -101,27 +100,6 @@ def load_fused_calibration(path: str) -> FusedCalibration:
     return FusedCalibration(fused_s=float(d["fused_s"]),
                             dequant_s=float(d["dequant_s"]),
                             attn_s=float(d["attn_s"]))
-
-
-def profile_decompression(methods: Dict[str, CompressionMethod],
-                          sample_kv: KVData,
-                          repeats: int = 3) -> DelayProfile:
-    """Measure actual decompress throughput on this host (estimator probe)."""
-    out: Dict[str, float] = {}
-    for name, m in methods.items():
-        if not m.applicable(sample_kv):
-            continue
-        rate = list(m.rates(sample_kv))[-1]
-        entry = m.compress(sample_kv, rate)
-        # offline calibration probe: measures REAL decompress
-        # throughput on this host  # simcheck: ignore[wallclock]
-        t0 = time.perf_counter()  # simcheck: ignore[wallclock]
-        for _ in range(repeats):
-            m.decompress(entry)
-        dt = (time.perf_counter() - t0) / repeats  # simcheck: ignore[wallclock]
-        out[name] = entry.nbytes / max(dt, 1e-9)
-    out.setdefault("none", float("inf"))
-    return DelayProfile(out)
 
 
 def load_delay_s(tier: Tier, nbytes: int, profile: DelayProfile,

@@ -16,6 +16,7 @@ from repro.core.compression.base import (
 )
 from repro.core.entry import EntryMeta
 from repro.core.policy import Move, Placement
+from repro.runtime.spans import span
 from repro.storage.tier import Tier
 
 
@@ -134,7 +135,8 @@ class Executor:
     def fetch(self, meta: EntryMeta) -> Tuple[KVData, CompressedEntry]:
         tier = self.tiers[meta.tier]
         entry = tier.get(meta.key)
-        kv = self.methods[meta.method].decompress(entry)
+        with span("decompress", method=meta.method):
+            kv = self.methods[meta.method].decompress(entry)
         return kv, entry
 
     # -- promotion (speculative prefetch) ------------------------------------

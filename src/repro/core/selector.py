@@ -47,6 +47,7 @@ from typing import Dict, List, Optional, Tuple
 
 from repro.core.entry import EntryMeta
 from repro.core.policy import Move
+from repro.runtime.spans import span
 
 
 class SelectorMismatch(AssertionError):
@@ -227,8 +228,9 @@ class IndexedSelector:
     # -- scoring --------------------------------------------------------------
     def _push(self, meta: EntryMeta, now: float) -> None:
         pol = self.c.policy
-        move = pol.entry_best_move(meta.tier, meta, now,
-                                   kv_lookup=self.c.executor.proxies.get)
+        with span("reprice", tier=meta.tier):
+            move = pol.entry_best_move(meta.tier, meta, now,
+                                       kv_lookup=self.c.executor.proxies.get)
         self.stats["entries_scored"] += 1
         if move is None:
             return                  # entry offers no move: nothing to rank

@@ -7,6 +7,8 @@ Dispatch policy:
 
 All entry points accept (T, F) arrays; K-style grouping (axis=0) runs the
 kernel directly, V-style (axis=1) transposes around the same kernel.
+Each runs under a ``named_scope`` (``kivi_quantize``/``kivi_dequantize``)
+so that a device trace attributes its operations.
 """
 from __future__ import annotations
 
@@ -33,40 +35,42 @@ def _interpret() -> bool:
 
 @functools.partial(jax.jit, static_argnames=("bits", "group_size", "axis"))
 def quantize(x: jax.Array, bits: int, group_size: int, axis: int) -> Quantized:
-    if not _use_pallas():
-        return _r.quantize_ref(x, bits, group_size, axis)
-    xx = x.T if axis == 1 else x
-    t, f = xx.shape
-    padded_f = (-f) % 128
-    if padded_f:
-        xx = jnp.pad(xx, ((0, 0), (0, padded_f)))
-    packed, scale, zero = _k.quantize_pallas(xx, bits, group_size,
-                                             interpret=_interpret())
-    if padded_f:
-        packed, scale, zero = packed[:, :f], scale[:, :f], zero[:, :f]
-    if axis == 1:
-        packed, scale, zero = packed.T, scale.T, zero.T
-    return Quantized(packed, scale, zero, bits, group_size, axis, t)
+    with jax.named_scope("kivi_quantize"):
+        if not _use_pallas():
+            return _r.quantize_ref(x, bits, group_size, axis)
+        xx = x.T if axis == 1 else x
+        t, f = xx.shape
+        padded_f = (-f) % 128
+        if padded_f:
+            xx = jnp.pad(xx, ((0, 0), (0, padded_f)))
+        packed, scale, zero = _k.quantize_pallas(xx, bits, group_size,
+                                                 interpret=_interpret())
+        if padded_f:
+            packed, scale, zero = packed[:, :f], scale[:, :f], zero[:, :f]
+        if axis == 1:
+            packed, scale, zero = packed.T, scale.T, zero.T
+        return Quantized(packed, scale, zero, bits, group_size, axis, t)
 
 
 @functools.partial(jax.jit, static_argnames=("out_dtype",))
 def dequantize(qt: Quantized, out_dtype=jnp.float32) -> jax.Array:
-    if not _use_pallas():
-        return _r.dequantize_ref(qt, out_dtype)
-    packed, scale, zero = qt.packed, qt.scale, qt.zero
-    if qt.axis == 1:
-        packed, scale, zero = packed.T, scale.T, zero.T
-    f = packed.shape[1]
-    padded_f = (-f) % 128
-    if padded_f:
-        packed = jnp.pad(packed, ((0, 0), (0, padded_f)))
-        scale = jnp.pad(scale, ((0, 0), (0, padded_f)))
-        zero = jnp.pad(zero, ((0, 0), (0, padded_f)))
-    x = _k.dequantize_pallas(packed, scale, zero, qt.bits, qt.group_size,
-                             out_dtype, interpret=_interpret())
-    if padded_f:
-        x = x[:, :f]
-    return x.T if qt.axis == 1 else x
+    with jax.named_scope("kivi_dequantize"):
+        if not _use_pallas():
+            return _r.dequantize_ref(qt, out_dtype)
+        packed, scale, zero = qt.packed, qt.scale, qt.zero
+        if qt.axis == 1:
+            packed, scale, zero = packed.T, scale.T, zero.T
+        f = packed.shape[1]
+        padded_f = (-f) % 128
+        if padded_f:
+            packed = jnp.pad(packed, ((0, 0), (0, padded_f)))
+            scale = jnp.pad(scale, ((0, 0), (0, padded_f)))
+            zero = jnp.pad(zero, ((0, 0), (0, padded_f)))
+        x = _k.dequantize_pallas(packed, scale, zero, qt.bits, qt.group_size,
+                                 out_dtype, interpret=_interpret())
+        if padded_f:
+            x = x[:, :f]
+        return x.T if qt.axis == 1 else x
 
 
 def quantize_kv(k: jax.Array, v: jax.Array, bits: int, group_size: int = 64):

@@ -34,12 +34,16 @@ class Model:
     def loss(self, params, batch, remat: bool = False):
         return transformer.loss_fn(params, self.cfg, batch, remat=remat)
 
+    # the named scopes tag every operation of the step in the device
+    # trace, so a profile attributes device time to prefill or decode
     def prefill(self, params, batch, capacity: int):
-        return transformer.prefill(params, self.cfg, batch, capacity)
+        with jax.named_scope("prefill"):
+            return transformer.prefill(params, self.cfg, batch, capacity)
 
     def decode_step(self, params, cache, cur_index, tokens, position=None):
-        return transformer.decode_step(params, self.cfg, cache, cur_index,
-                                       tokens, position)
+        with jax.named_scope("decode_step"):
+            return transformer.decode_step(params, self.cfg, cache,
+                                           cur_index, tokens, position)
 
     def init_cache(self, batch: int, capacity: int, enc_len: int = 0,
                    kv_bits: int = 16):

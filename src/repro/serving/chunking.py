@@ -45,6 +45,7 @@ import numpy as np
 from repro.core.compression.base import KVData
 from repro.core.controller import AdaptCacheController, FetchResult, Transfer
 from repro.core.estimator import QualityEstimator
+from repro.runtime.spans import span
 
 PAGE_TOKENS = 256
 TOKEN_ARRAYS = ("k", "v", "ckv", "krope", "positions")
@@ -332,45 +333,52 @@ class PagedPrefixCache:
         consults the remainder, so evicting any base page implicitly
         invalidates it. The caller books the piece reads on the owning
         tiers' I/O channels."""
-        keys = page_keys(tokens, self.page_tokens) if keys is None else keys
-        rkey = (remainder_key(tokens, self.page_tokens)
-                if self.remainder else None)
-        fetched: List[Tuple[str, FetchResult]] = []
-        for key in keys:
-            if self.controller.lookup(key) is None:
-                break
-            r = self.controller.fetch(key, now=now, replica=replica)
-            if r is None:
-                break
-            fetched.append((key, r))
-        rem_tokens = 0
-        if self.remainder and len(fetched) == len(keys):
-            if rkey is not None and self.controller.lookup(rkey) is not None:
-                r = self.controller.fetch(rkey, now=now, replica=replica)
-                if r is not None:
-                    fetched.append((rkey, r))
-                    rem_tokens = (len(tokens)
-                                  - len(keys) * self.page_tokens)
-        self.controller.note_page_run(
-            len(fetched) - (1 if rem_tokens else 0), len(keys),
-            run_key=keys[0] if keys else None, keys=keys, now=now,
-            rem_hit=rem_tokens > 0, rem_key=rkey)
-        if not fetched:
-            return FetchPlan([], 0, 0, None)
-        kv = join_kv([f.kv for _, f in fetched])
-        # dropped pages shrink; count ACTUAL kept tokens
-        n_tokens = kv["k" if "k" in kv else "ckv"].shape[1]
-        n_page_hits = len(fetched) - (1 if rem_tokens else 0)
-        pages = [PageFetch(key, f.tier, f.nbytes, f.method, f.rate, f.kv,
-                           f.remote, f.xlink_delay_s, f.decompress_delay_s,
-                           f.load_delay_s, orig_nbytes=f.orig_nbytes,
-                           n_tokens=(rem_tokens
-                                     if (rem_tokens and i == len(fetched) - 1)
-                                     else self.page_tokens))
-                 for i, (key, f) in enumerate(fetched)]
-        return FetchPlan(pages, n_page_hits * self.page_tokens + rem_tokens,
-                         n_tokens, kv, remainder_tokens=rem_tokens,
-                         quality=self._compose_quality(fetched, rem_tokens))
+        with span("prefix_match"):
+            if keys is None:
+                keys = page_keys(tokens, self.page_tokens)
+            rkey = (remainder_key(tokens, self.page_tokens)
+                    if self.remainder else None)
+            fetched: List[Tuple[str, FetchResult]] = []
+            for key in keys:
+                if self.controller.lookup(key) is None:
+                    break
+                r = self.controller.fetch(key, now=now, replica=replica)
+                if r is None:
+                    break
+                fetched.append((key, r))
+            rem_tokens = 0
+            if self.remainder and len(fetched) == len(keys):
+                if (rkey is not None
+                        and self.controller.lookup(rkey) is not None):
+                    r = self.controller.fetch(rkey, now=now, replica=replica)
+                    if r is not None:
+                        fetched.append((rkey, r))
+                        rem_tokens = (len(tokens)
+                                      - len(keys) * self.page_tokens)
+            self.controller.note_page_run(
+                len(fetched) - (1 if rem_tokens else 0), len(keys),
+                run_key=keys[0] if keys else None, keys=keys, now=now,
+                rem_hit=rem_tokens > 0, rem_key=rkey)
+            if not fetched:
+                return FetchPlan([], 0, 0, None)
+            with span("kv_join", pieces=len(fetched)):
+                kv = join_kv([f.kv for _, f in fetched])
+            # dropped pages shrink; count ACTUAL kept tokens
+            n_tokens = kv["k" if "k" in kv else "ckv"].shape[1]
+            n_page_hits = len(fetched) - (1 if rem_tokens else 0)
+            last = len(fetched) - 1
+            pages = [PageFetch(key, f.tier, f.nbytes, f.method, f.rate, f.kv,
+                               f.remote, f.xlink_delay_s,
+                               f.decompress_delay_s, f.load_delay_s,
+                               orig_nbytes=f.orig_nbytes,
+                               n_tokens=(rem_tokens
+                                         if (rem_tokens and i == last)
+                                         else self.page_tokens))
+                     for i, (key, f) in enumerate(fetched)]
+            return FetchPlan(
+                pages, n_page_hits * self.page_tokens + rem_tokens, n_tokens,
+                kv, remainder_tokens=rem_tokens,
+                quality=self._compose_quality(fetched, rem_tokens))
 
     def _compose_quality(self, fetched: List[Tuple[str, FetchResult]],
                          rem_tokens: int) -> float:

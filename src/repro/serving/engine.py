@@ -132,6 +132,7 @@ import numpy as np
 
 from repro.configs.base import LayerKind
 from repro.core.controller import AdaptCacheController, SimClock, Transfer
+from repro.runtime.spans import mark, span
 from repro.serving.chunking import (
     PagedPrefixCache, join_kv, page_keys, tail_kv,
 )
@@ -1127,7 +1128,10 @@ class ServingEngine:
                            ctx.task_type))
 
         def issue(rep: _Replica, now: float) -> None:
-            rep.issue(now, lambda lane, req, t: dispatch(rep, lane, req, t))
+            def traced(lane: int, req: Request, t: float) -> None:
+                with span("dispatch", req_id=req.req_id):
+                    dispatch(rep, lane, req, t)
+            rep.issue(now, traced)
 
         req_by_id = {r.req_id: r for r in requests}
         for req in requests:
@@ -1137,133 +1141,136 @@ class ServingEngine:
 
         while loop:
             now, kind, payload = loop.pop()
-            tick_time(now)
-            if kind == EV_ARRIVAL:
-                req = payload
-                rep = route(req)
-                rep.waiting.append(req)
-                note(now, "arrival", req_id=req.req_id, replica=rep.idx)
-                issue(rep, now)
-                maybe_prefetch(now, rep)
-
-            elif kind == EV_CHUNK_DONE:
-                job = payload
-                job.ci += 1
-                note(now, "chunk_done", req_id=job.req.req_id,
-                     replica=job.rep.idx, idx=job.ci - 1,
-                     remaining=len(job.chunks) - job.ci)
-                if job.ci < len(job.chunks):
-                    issue_chunk(job, now)
-                elif job.pipelined and job.loads_pending:
-                    job.chunks_done = True  # compute beat the page I/O;
-                    #                         admission fences on the loads
-                else:
-                    finish_job(job, now)
-
-            elif kind == EV_LOAD_DONE and isinstance(payload, _PagedJob):
-                job = payload
-                job.t_load_done = now
-                if job.pipelined:
-                    job.loads_pending = False
-                    if job.chunks_done:     # compute already finished
-                        finish_job(job, now)
-                    # else: the in-flight chunk chain admits the job
-                elif job.chunks:        # fetch-then-compute: the suffix
-                    issue_chunk(job, now)   # starts once the pages landed
-                else:
-                    finish_job(job, now)    # pure page hit
-
-            elif kind in (EV_LOAD_DONE, EV_PREFILL_DONE):
-                rep, lane, req, kv, orig_len, issue_t, extra = payload
-                if kind == EV_PREFILL_DONE:
-                    hit = {"hit_tier": None, "method": "none", "rate": 1.0}
-                    if isinstance(extra, str):       # owner of the prefill
-                        transfers: List[Transfer] = []
-                        self.controller.insert(
-                            req.context_key, kv, extra, now=now,
-                            transfers=transfers, replica=rep.idx,
-                            tenant=self.contexts[req.context_key].tenant)
-                        rep.inflight.pop(req.context_key, None)
-                        booked = book(now, transfers, "insert")
-                        for tr, q_s, x_s in booked:
-                            if tr.kind == "insert":
-                                hit["wb_queue_s"] = q_s
-                                hit["wb_transfer_s"] = x_s
-                    timing = {"load_s": 0.0, "prefill_s": now - issue_t}
-                    kv_frac = 1.0
-                else:
-                    hit = extra
-                    timing = {"load_s": now - issue_t, "prefill_s": 0.0}
-                    kv_frac = hit.pop("_kv_frac", 1.0)
-                rep.admit(lane, req, kv, orig_len, now, kv_frac=kv_frac)
-                pending[req.req_id] = {
-                    "queue_s": issue_t - req.arrival_s, **timing, **hit,
-                    "replica": rep.idx}
-                note(now, EVENT_NAMES[kind], req_id=req.req_id,
-                     replica=rep.idx, lane=lane)
-                rep.ensure_tick(loop, now)
-                maybe_prefetch(now, rep)
-
-            elif kind == EV_WRITE_DONE:
-                tr, cause = payload
-                if san is not None:
-                    san.note_transfer_done(tr, now)
-                if ready_at.get(tr.key, 0.0) <= now:
-                    ready_at.pop(tr.key, None)
-                if tr.kind == "promote":
-                    if tr.key in ra_writes:     # readahead budget, not
-                        ra_writes.discard(tr.key)   # the entry-prefetch one
-                        ra_count[0] -= 1
-                    else:
-                        pf_inflight[0] -= 1
-                note(now, "write_done", key=tr.key, move=tr.kind,
-                     tier=tr.dst_tier, cause=cause)
-                maybe_prefetch(now)
-
-            elif kind == EV_TICK:
-                rep = payload
-                done = rep.tick(loop, now)
-                if done is None:            # all lanes idle; chain stopped
+            with span("event", kind=EVENT_NAMES[kind]):
+                tick_time(now)
+                if kind == EV_ARRIVAL:
+                    req = payload
+                    mark("arrival", req_id=req.req_id)
+                    rep = route(req)
+                    rep.waiting.append(req)
+                    note(now, "arrival", req_id=req.req_id, replica=rep.idx)
+                    issue(rep, now)
                     maybe_prefetch(now, rep)
-                    if san is not None:
-                        san.after_event(now, kind)
-                    continue
-                note(now, "tick", replica=rep.idx, finished=len(done),
-                     lanes=sum(s.active for s in rep.batcher.slots)
-                     + len(done))
-                for sched in done:
-                    rec = pending.pop(sched.req_id)
-                    req = req_by_id[sched.req_id]
-                    ctx = self.contexts[sched.context_key]
-                    non_decode = (rec["queue_s"] + rec["load_s"]
-                                  + rec["prefill_s"])
-                    results.append(RequestResult(
-                        sched.req_id, sched.context_key, ctx.task_type,
-                        req.arrival_s, sched.ttft_s, rec["queue_s"],
-                        rec["load_s"], rec["prefill_s"], rec["hit_tier"],
-                        rec["method"], rec["rate"],
-                        self._score(req, ctx, sched.tokens, skip_quality),
-                        sched.tokens,
-                        decode_s=sched.ttft_s - non_decode,
-                        finish_s=sched.finish_s, replica=rec["replica"],
-                        truncated=sched.truncated,
-                        prefetch_hit=rec.get("prefetch_hit", False),
-                        write_wait_s=rec.get("write_wait_s", 0.0),
-                        wb_queue_s=rec.get("wb_queue_s", 0.0),
-                        wb_transfer_s=rec.get("wb_transfer_s", 0.0),
-                        remote_hit=rec.get("remote_hit", False),
-                        pages_hit=rec.get("pages_hit", 0),
-                        tokens_reused_frac=rec.get("tokens_reused_frac",
-                                                   0.0),
-                        remainder_hit=rec.get("remainder_hit", False),
-                        composed_quality=rec.get("composed_quality",
-                                                 1.0),
-                        tenant=ctx.tenant))
-                issue(rep, now)
-                maybe_prefetch(now, rep)
 
-            if san is not None:
-                san.after_event(now, kind)
+                elif kind == EV_CHUNK_DONE:
+                    job = payload
+                    job.ci += 1
+                    note(now, "chunk_done", req_id=job.req.req_id,
+                         replica=job.rep.idx, idx=job.ci - 1,
+                         remaining=len(job.chunks) - job.ci)
+                    if job.ci < len(job.chunks):
+                        issue_chunk(job, now)
+                    elif job.pipelined and job.loads_pending:
+                        job.chunks_done = True  # compute beat the page I/O;
+                        #                         admission fences on the loads
+                    else:
+                        finish_job(job, now)
+
+                elif kind == EV_LOAD_DONE and isinstance(payload, _PagedJob):
+                    job = payload
+                    job.t_load_done = now
+                    if job.pipelined:
+                        job.loads_pending = False
+                        if job.chunks_done:     # compute already finished
+                            finish_job(job, now)
+                        # else: the in-flight chunk chain admits the job
+                    elif job.chunks:        # fetch-then-compute: the suffix
+                        issue_chunk(job, now)   # starts once the pages landed
+                    else:
+                        finish_job(job, now)    # pure page hit
+
+                elif kind in (EV_LOAD_DONE, EV_PREFILL_DONE):
+                    rep, lane, req, kv, orig_len, issue_t, extra = payload
+                    if kind == EV_PREFILL_DONE:
+                        hit = {"hit_tier": None, "method": "none", "rate": 1.0}
+                        if isinstance(extra, str):       # owner of the prefill
+                            transfers: List[Transfer] = []
+                            self.controller.insert(
+                                req.context_key, kv, extra, now=now,
+                                transfers=transfers, replica=rep.idx,
+                                tenant=self.contexts[req.context_key].tenant)
+                            rep.inflight.pop(req.context_key, None)
+                            booked = book(now, transfers, "insert")
+                            for tr, q_s, x_s in booked:
+                                if tr.kind == "insert":
+                                    hit["wb_queue_s"] = q_s
+                                    hit["wb_transfer_s"] = x_s
+                        timing = {"load_s": 0.0, "prefill_s": now - issue_t}
+                        kv_frac = 1.0
+                    else:
+                        hit = extra
+                        timing = {"load_s": now - issue_t, "prefill_s": 0.0}
+                        kv_frac = hit.pop("_kv_frac", 1.0)
+                    rep.admit(lane, req, kv, orig_len, now, kv_frac=kv_frac)
+                    pending[req.req_id] = {
+                        "queue_s": issue_t - req.arrival_s, **timing, **hit,
+                        "replica": rep.idx}
+                    note(now, EVENT_NAMES[kind], req_id=req.req_id,
+                         replica=rep.idx, lane=lane)
+                    rep.ensure_tick(loop, now)
+                    maybe_prefetch(now, rep)
+
+                elif kind == EV_WRITE_DONE:
+                    tr, cause = payload
+                    if san is not None:
+                        san.note_transfer_done(tr, now)
+                    if ready_at.get(tr.key, 0.0) <= now:
+                        ready_at.pop(tr.key, None)
+                    if tr.kind == "promote":
+                        # readahead budget, not the entry-prefetch one
+                        if tr.key in ra_writes:
+                            ra_writes.discard(tr.key)
+                            ra_count[0] -= 1
+                        else:
+                            pf_inflight[0] -= 1
+                    note(now, "write_done", key=tr.key, move=tr.kind,
+                         tier=tr.dst_tier, cause=cause)
+                    maybe_prefetch(now)
+
+                elif kind == EV_TICK:
+                    rep = payload
+                    done = rep.tick(loop, now)
+                    if done is None:            # all lanes idle; chain stopped
+                        maybe_prefetch(now, rep)
+                        if san is not None:
+                            san.after_event(now, kind)
+                        continue
+                    note(now, "tick", replica=rep.idx, finished=len(done),
+                         lanes=sum(s.active for s in rep.batcher.slots)
+                         + len(done))
+                    for sched in done:
+                        rec = pending.pop(sched.req_id)
+                        req = req_by_id[sched.req_id]
+                        ctx = self.contexts[sched.context_key]
+                        non_decode = (rec["queue_s"] + rec["load_s"]
+                                      + rec["prefill_s"])
+                        results.append(RequestResult(
+                            sched.req_id, sched.context_key, ctx.task_type,
+                            req.arrival_s, sched.ttft_s, rec["queue_s"],
+                            rec["load_s"], rec["prefill_s"], rec["hit_tier"],
+                            rec["method"], rec["rate"],
+                            self._score(req, ctx, sched.tokens, skip_quality),
+                            sched.tokens,
+                            decode_s=sched.ttft_s - non_decode,
+                            finish_s=sched.finish_s, replica=rec["replica"],
+                            truncated=sched.truncated,
+                            prefetch_hit=rec.get("prefetch_hit", False),
+                            write_wait_s=rec.get("write_wait_s", 0.0),
+                            wb_queue_s=rec.get("wb_queue_s", 0.0),
+                            wb_transfer_s=rec.get("wb_transfer_s", 0.0),
+                            remote_hit=rec.get("remote_hit", False),
+                            pages_hit=rec.get("pages_hit", 0),
+                            tokens_reused_frac=rec.get("tokens_reused_frac",
+                                                       0.0),
+                            remainder_hit=rec.get("remainder_hit", False),
+                            composed_quality=rec.get("composed_quality",
+                                                     1.0),
+                            tenant=ctx.tenant))
+                    issue(rep, now)
+                    maybe_prefetch(now, rep)
+
+                if san is not None:
+                    san.after_event(now, kind)
 
         if san is not None:
             san.finish(loop.now)

@@ -22,6 +22,7 @@ from repro.configs.base import AttnKind, LayerKind, ModelConfig
 from repro.core.compression.base import KVData
 from repro.models import Model
 from repro.models.transformer import _prefix_count
+from repro.runtime.spans import span
 
 
 def _layer_cache_refs(cache, cfg: ModelConfig):
@@ -129,9 +130,11 @@ class ModelRunner:
     # -- prefill -> storable entry -------------------------------------------
     def prefill_entry(self, ctx_tokens: np.ndarray) -> KVData:
         t = len(ctx_tokens)
-        batch = {"tokens": jnp.asarray(ctx_tokens, jnp.int32)[None]}
-        _, cache = self.model.prefill(self.params, batch, capacity=self.capacity)
-        return cache_to_kvdata(cache, self.model.cfg, t)
+        with span("prefill", tokens=t):
+            batch = {"tokens": jnp.asarray(ctx_tokens, jnp.int32)[None]}
+            _, cache = self.model.prefill(self.params, batch,
+                                          capacity=self.capacity)
+            return cache_to_kvdata(cache, self.model.cfg, t)
 
     # -- generation ------------------------------------------------------------
     def generate_from_kvdata(self, kv: KVData, orig_len: int,

@@ -42,6 +42,7 @@ import numpy as np
 from repro.configs.base import AttnKind, LayerKind, ModelConfig
 from repro.core.compression.base import KVData
 from repro.models import Model
+from repro.runtime.spans import mark, span
 from repro.serving.runner import _layer_cache_refs
 from repro.serving.timemodel import TimeModel
 from repro.serving.workload import Request
@@ -125,43 +126,49 @@ class ContinuousBatcher:
         Updates are per-leaf ``.at[...].set`` on the target lane only —
         no host round-trip of the whole batched cache pytree (the seed
         version copied every lane of every layer through numpy on each
-        admission, an O(whole-cache) transfer per request).
+        admission, an O(whole-cache) transfer per request). The
+        ``lane_write`` span carries the bytes that cross from host to
+        device: each host array, cast on the host to the cache dtype.
         """
         cfg = self.model.cfg
         n_kept = int(kv["positions"].shape[0]) if "positions" in kv else 0
         ai = mi = 0
         hd = cfg.resolved_head_dim
+        # (leaf dict, leaf name, value, group index, whole lane)
+        writes = []
         for i, kind, (sect, j, g) in _layer_cache_refs(self.cache, cfg):
             blk = self.cache[sect][j]
-
-            # stored entries are float32; the lane cache holds the
-            # model dtype, so cast before the scatter
-            def put(d, name, val):
-                val = jnp.asarray(val, d[name].dtype)
-                if g is not None:
-                    d[name] = d[name].at[g, lane, :val.shape[0]].set(val)
-                else:
-                    d[name] = d[name].at[lane, :val.shape[0]].set(val)
-
-            def put_full(d, name, val):
-                val = jnp.asarray(val, d[name].dtype)
-                if g is not None:
-                    d[name] = d[name].at[g, lane].set(val)
-                else:
-                    d[name] = d[name].at[lane].set(val)
-
             if kind == LayerKind.MAMBA:
-                put_full(blk["mamba"], "ssm", kv["ssm"][mi])
-                put_full(blk["mamba"], "conv", kv["conv"][mi])
+                writes += [(blk["mamba"], "ssm", kv["ssm"][mi], g, True),
+                           (blk["mamba"], "conv", kv["conv"][mi], g, True)]
                 mi += 1
             elif cfg.attn_kind == AttnKind.MLA:
-                put(blk["self"], "ckv", kv["ckv"][ai])
-                put(blk["self"], "krope", kv["krope"][ai])
+                writes += [(blk["self"], "ckv", kv["ckv"][ai], g, False),
+                           (blk["self"], "krope", kv["krope"][ai], g, False)]
                 ai += 1
             else:
-                put(blk["self"], "k", kv["k"][ai].reshape(n_kept, -1, hd))
-                put(blk["self"], "v", kv["v"][ai].reshape(n_kept, -1, hd))
+                writes += [(blk["self"], "k",
+                            kv["k"][ai].reshape(n_kept, -1, hd), g, False),
+                           (blk["self"], "v",
+                            kv["v"][ai].reshape(n_kept, -1, hd), g, False)]
                 ai += 1
+        h2d = sum(int(np.size(val)) * d[name].dtype.itemsize
+                  for d, name, val, _, _ in writes
+                  if not isinstance(val, jax.Array))
+        with span("lane_write", h2d_bytes=h2d):
+            for d, name, val, g, whole in writes:
+                # stored entries are float32; the lane cache holds the
+                # model dtype, so cast before the scatter
+                val = jnp.asarray(val, d[name].dtype)
+                n = val.shape[0]
+                if whole and g is not None:
+                    d[name] = d[name].at[g, lane].set(val)
+                elif whole:
+                    d[name] = d[name].at[lane].set(val)
+                elif g is not None:
+                    d[name] = d[name].at[g, lane, :n].set(val)
+                else:
+                    d[name] = d[name].at[lane, :n].set(val)
         return n_kept
 
     def free_lanes(self) -> List[int]:
@@ -169,11 +176,14 @@ class ContinuousBatcher:
 
     def admit(self, lane: int, req: Request, kv: KVData, orig_len: int,
               now: float, kv_frac: float = 1.0) -> None:
-        n_kept = self._write_lane(lane, kv)
-        self.slots[lane] = SlotState(
-            req=req, started_s=now, write_slot=n_kept, position=orig_len,
-            pending=list(np.asarray(req.question, np.int64)),
-            kv_frac=kv_frac)
+        with span("admit", req_id=req.req_id):
+            n_kept = self._write_lane(lane, kv)
+            self.slots[lane] = SlotState(
+                req=req, started_s=now, write_slot=n_kept,
+                position=orig_len,
+                pending=list(np.asarray(req.question, np.int64)),
+                kv_frac=kv_frac)
+        mark("admitted", req_id=req.req_id)
 
     def _decode_kvb(self, active: List[int]) -> Optional[float]:
         """Per-token KV-read bytes override for the next decode step:
@@ -216,9 +226,12 @@ class ContinuousBatcher:
                             else (s.generated[-1] if s.generated else 0))
             write[i] = min(s.write_slot, self.capacity - 1)
             pos[i] = s.position
-        logits, self.cache = self._decode(
-            self.params, self.cache, jnp.asarray(write),
-            jnp.asarray(tokens), jnp.asarray(pos))
+        with span("decode_tick", lanes=len(active),
+                  positions=int(pos.sum())):
+            logits, self.cache = self._decode(
+                self.params, self.cache, jnp.asarray(write),
+                jnp.asarray(tokens), jnp.asarray(pos))
+            nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
 
         max_ctx = max(self.slots[i].position for i in active)
         dt = self.tm.decode_step_s(len(active), max_ctx,
@@ -226,7 +239,6 @@ class ContinuousBatcher:
                                        active))
 
         done: List[ScheduledResult] = []
-        nxt = np.asarray(jnp.argmax(logits[:, 0], axis=-1))
         for i in active:
             s = self.slots[i]
             s.write_slot += 1
@@ -241,6 +253,8 @@ class ContinuousBatcher:
                         s.ttft_s = now + dt - s.req.arrival_s
             else:
                 s.generated.append(int(nxt[i]))
+            if len(s.generated) == 1 and not s.pending:
+                mark("first_token", req_id=s.req.req_id)
             answered = (not s.pending
                         and len(s.generated) >= s.req.max_new_tokens)
             out_of_capacity = s.write_slot >= self.capacity

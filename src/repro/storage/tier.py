@@ -9,7 +9,8 @@ device spec abstraction so the same policy runs with TPU-host constants
     under a spool directory — bytes genuinely leave memory;
   * delay accounting is a calibrated model (default: the paper's 1 GB/s
     disk; DRAM->device 16 GB/s PCIe-class) so benchmark numbers are
-    host-independent, while ``measure=True`` uses actual wall-clock I/O.
+    host-independent; the real I/O shows in a profile as the
+    ``tier_get``/``tier_put`` spans.
 """
 from __future__ import annotations
 
@@ -17,7 +18,6 @@ import dataclasses
 import os
 import struct
 import tempfile
-import time
 import zlib
 from typing import Any, Dict, Iterable, Optional, Tuple
 
@@ -25,6 +25,7 @@ import numpy as np
 import zstandard
 
 from repro.core.compression.base import CompressedEntry
+from repro.runtime.spans import span
 
 
 @dataclasses.dataclass(frozen=True)
@@ -114,7 +115,8 @@ class DRAMTier(Tier):
         if key in self._store:
             self.evict(key)
         nb = entry.nbytes
-        self._store[key] = entry
+        with span("tier_put", tier=self.name, nbytes=nb):
+            self._store[key] = entry
         self._meta[key] = {"nbytes": nb, "method": entry.method,
                            "rate": entry.rate}
         self.used_bytes += nb
@@ -122,7 +124,9 @@ class DRAMTier(Tier):
         return nb
 
     def get(self, key: str) -> CompressedEntry:
-        return self._store[key]
+        with span("tier_get", tier=self.name,
+                  nbytes=self._meta[key]["nbytes"]):
+            return self._store[key]
 
     def evict(self, key: str) -> None:
         self.used_bytes -= self._meta.pop(key)["nbytes"]
@@ -137,11 +141,10 @@ class SSDTier(Tier):
     """File-backed tier: one zstd-framed, CRC-checked file per entry."""
 
     def __init__(self, spec: DeviceSpec = PAPER_SSD,
-                 root: Optional[str] = None, measure: bool = False,
+                 root: Optional[str] = None,
                  name: Optional[str] = None):
         super().__init__(spec, name=name)
         self.root = root or tempfile.mkdtemp(prefix="adaptcache_ssd_")
-        self.measure = measure
         self._cctx = zstandard.ZstdCompressor(level=1)
         self._dctx = zstandard.ZstdDecompressor()
         os.makedirs(self.root, exist_ok=True)
@@ -152,19 +155,20 @@ class SSDTier(Tier):
     def put(self, key: str, entry: CompressedEntry) -> int:
         if key in self._meta:
             self.evict(key)
-        raw = entry.tobytes()
-        framed = self._cctx.compress(raw)
-        crc = zlib.crc32(raw)
-        path = self._path(key)
-        tmp = path + ".tmp"
-        with open(tmp, "wb") as f:
-            f.write(_MAGIC)
-            f.write(_HEADER.pack(crc, len(raw)))
-            f.write(framed)
-        os.replace(tmp, path)                       # atomic
         # capacity accounting uses the LOGICAL entry size (policy view);
         # frame compression is transparent transport compression.
         nb = entry.nbytes
+        with span("tier_put", tier=self.name, nbytes=nb):
+            raw = entry.tobytes()
+            framed = self._cctx.compress(raw)
+            crc = zlib.crc32(raw)
+            path = self._path(key)
+            tmp = path + ".tmp"
+            with open(tmp, "wb") as f:
+                f.write(_MAGIC)
+                f.write(_HEADER.pack(crc, len(raw)))
+                f.write(framed)
+            os.replace(tmp, path)                   # atomic
         self._meta[key] = {"nbytes": nb, "method": entry.method,
                            "rate": entry.rate, "meta": entry.meta,
                            "disk_bytes": len(framed) + 4 + _HEADER.size,
@@ -175,21 +179,17 @@ class SSDTier(Tier):
 
     def get(self, key: str) -> CompressedEntry:
         info = self._meta[key]
-        # measure=True times REAL host I/O (calibration aid), not
-        # simulated time  # simcheck: ignore[wallclock]
-        t0 = time.perf_counter()  # simcheck: ignore[wallclock]
-        with open(info["path"], "rb") as f:
-            assert f.read(4) == _MAGIC, f"corrupt frame for {key}"
-            crc, orig_len = _HEADER.unpack(f.read(_HEADER.size))
-            raw = self._dctx.decompress(f.read(), max_output_size=orig_len)
-        if zlib.crc32(raw) != crc:
-            raise IOError(f"CRC mismatch for entry {key} — corrupt SSD page")
-        entry = CompressedEntry.frombytes(raw, info["method"], info["rate"],
-                                          info["meta"])
-        if self.measure:
-            info["last_read_s"] = (time.perf_counter()  # simcheck: ignore[wallclock]
-                                   - t0)
-        return entry
+        with span("tier_get", tier=self.name, nbytes=info["nbytes"]):
+            with open(info["path"], "rb") as f:
+                assert f.read(4) == _MAGIC, f"corrupt frame for {key}"
+                crc, orig_len = _HEADER.unpack(f.read(_HEADER.size))
+                raw = self._dctx.decompress(f.read(),
+                                            max_output_size=orig_len)
+            if zlib.crc32(raw) != crc:
+                raise IOError(
+                    f"CRC mismatch for entry {key} — corrupt SSD page")
+            return CompressedEntry.frombytes(raw, info["method"],
+                                             info["rate"], info["meta"])
 
     def evict(self, key: str) -> None:
         info = self._meta.pop(key)

@@ -13,7 +13,8 @@ module registers itself here:
 Suppression levels:
 
 * ``# simcheck: ignore[rule]`` on the offending line — for sites that
-  are intentional by design (e.g. ``measure=True`` wall-clock I/O);
+  are intentional by design (e.g. the controller's wall-clock default
+  ``clock`` for standalone use, which serving rigs replace);
 * the checked-in baseline file — for grandfathered findings OUTSIDE
   ``serving/``/``storage/``/``core/`` only. Baseline keys are
   name-based (``path::rule::symbol``), not line-based, so unrelated
