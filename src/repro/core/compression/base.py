@@ -24,6 +24,14 @@ def kv_nbytes(kv: KVData) -> int:
     return int(sum(a.nbytes for a in kv.values()))
 
 
+def shape_proxy(kv: KVData) -> KVData:
+    """Zero-stride stand-in with the same shapes and dtypes, for size
+    estimates: every element aliases one zero, so it holds no storage, but
+    any fancy index or copy of it builds a full-size array."""
+    return {k: np.broadcast_to(np.zeros((), a.dtype), a.shape)
+            for k, a in kv.items()}
+
+
 def kv_num_tokens(kv: KVData) -> int:
     if "k" in kv:
         return int(kv["k"].shape[1])

@@ -34,8 +34,10 @@ class DropQuantCompression(CompressionMethod):
                      for k, b in self.grid)
 
     def _est(self, kv: KVData, keep: float, bits: int) -> int:
-        dropped = self.stream.compress(kv, keep)   # cheap: slicing only
-        return self.kivi.estimate_quantized_nbytes(dropped.arrays, bits)
+        # from shapes alone: compress() would copy the kept tokens, even of
+        # a zero-stride shape proxy
+        return self.kivi.estimate_quantized_nbytes(
+            self.stream.kept_proxy(kv, keep), bits)
 
     def _pick(self, kv: KVData, rate: float):
         ladder = self.rates(kv)

@@ -39,9 +39,11 @@ class StreamingLLMCompression(CompressionMethod):
     def rates(self, kv: Optional[KVData] = None) -> Sequence[float]:
         return KEEP_LADDER
 
+    def _n_keep(self, t: int, keep_frac: float) -> int:
+        return min(max(self.n_sink + 1, int(round(t * keep_frac))), t)
+
     def _keep_indices(self, t: int, keep_frac: float) -> np.ndarray:
-        n_keep = max(self.n_sink + 1, int(round(t * keep_frac)))
-        n_keep = min(n_keep, t)
+        n_keep = self._n_keep(t, keep_frac)
         n_recent = n_keep - self.n_sink
         if n_recent <= 0:
             return np.arange(n_keep)
@@ -72,14 +74,23 @@ class StreamingLLMCompression(CompressionMethod):
     def decompress(self, entry: CompressedEntry) -> KVData:
         return dict(entry.arrays)
 
-    def estimate_nbytes(self, kv: KVData, rate: float) -> int:
-        keep = self.closest_rate(kv, rate)
-        t = self._token_dim(kv)
-        n_keep = len(self._keep_indices(t, keep))
-        total = 0
+    def kept_proxy(self, kv: KVData, rate: float) -> KVData:
+        """What ``compress(kv, rate)`` keeps, from shapes alone: zero-stride
+        views with its arrays' shapes and dtypes, no token copied."""
+        n_keep = self._n_keep(self._token_dim(kv), self.closest_rate(kv, rate))
+        kept = {}
         for name, a in kv.items():
-            if name in self.TOKEN_ARRAYS or name == "positions":
-                total += a.nbytes * n_keep // t
+            if name == "positions":
+                shape = (n_keep,) + a.shape[1:]
+            elif name in self.TOKEN_ARRAYS:
+                shape = a.shape[:1] + (n_keep,) + a.shape[2:]
             else:
-                total += a.nbytes
-        return int(total) + (0 if "positions" in kv else 4 * n_keep)
+                shape = a.shape
+            kept[name] = np.broadcast_to(np.zeros((), a.dtype), shape)
+        if "positions" not in kv:
+            kept["positions"] = np.broadcast_to(np.zeros((), np.int32),
+                                                (n_keep,))
+        return kept
+
+    def estimate_nbytes(self, kv: KVData, rate: float) -> int:
+        return kv_nbytes(self.kept_proxy(kv, rate))

@@ -12,18 +12,12 @@ from typing import Dict, List, Optional, Tuple
 import numpy as np
 
 from repro.core.compression.base import (
-    CompressedEntry, CompressionMethod, KVData,
+    CompressedEntry, CompressionMethod, KVData, shape_proxy,
 )
 from repro.core.entry import EntryMeta
 from repro.core.policy import Move, Placement
 from repro.runtime.spans import span
 from repro.storage.tier import Tier
-
-
-def shape_proxy(kv: KVData) -> KVData:
-    """Zero-storage stand-in with identical shapes/dtypes (for estimates)."""
-    return {k: np.broadcast_to(np.zeros((), a.dtype), a.shape)
-            for k, a in kv.items()}
 
 
 class Executor:

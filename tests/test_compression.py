@@ -1,4 +1,7 @@
 """Compression methods: roundtrip, analytic size == actual, semantics."""
+import tracemalloc
+
+import ml_dtypes
 import numpy as np
 import pytest
 
@@ -6,6 +9,7 @@ from repro.core.compression import (
     DropQuantCompression, KIVICompression, NoCompression,
     StreamingLLMCompression, default_registry, kv_nbytes,
 )
+from repro.core.compression.base import shape_proxy
 
 RNG = np.random.RandomState(4)
 
@@ -96,6 +100,55 @@ def test_drop_kivi_composes():
     d = m.decompress(c)
     assert d["k"].shape[1] < 128                 # dropped
     assert c.nbytes < 0.06 * kv_nbytes(kv)
+
+
+# (L, T, F, dtype, positions, names): T around n_sink + 1 (n_sink 4), T off
+# the KIVI group (64), F under the group, half precisions, MLA latents
+DROP_KIVI_SHAPES = [
+    (2, 4, 64, np.float32, True, ("k", "v")),
+    (2, 5, 64, np.float32, False, ("k", "v")),
+    (2, 6, 64, np.float16, True, ("k", "v")),
+    (3, 17, 32, np.float32, True, ("k", "v")),
+    (1, 100, 96, ml_dtypes.bfloat16, True, ("k", "v")),
+    (2, 130, 48, np.float16, False, ("k", "v")),
+    (4, 256, 128, ml_dtypes.bfloat16, False, ("k", "v")),
+    (3, 100, 32, np.float32, True, ("ckv", "krope")),
+    (2, 65, 64, ml_dtypes.bfloat16, False, ("ckv", "krope")),
+]
+
+
+@pytest.mark.parametrize("L,T,F,dtype,positions,names", DROP_KIVI_SHAPES)
+def test_drop_kivi_estimate_from_shapes(L, T, F, dtype, positions, names):
+    """The shape-only estimate is the compressed size of the real arrays,
+    and a shape proxy prices the ladder exactly as the arrays do."""
+    m = DropQuantCompression()
+    widths = {"krope": 16}
+    kv = {n: RNG.randn(L, T, widths.get(n, F)).astype(dtype) for n in names}
+    if positions:
+        kv["positions"] = np.arange(T, dtype=np.int32)
+    rates = m.rates(kv)
+    assert m.rates(shape_proxy(kv)) == rates
+    for rate in rates:
+        est = m.estimate_nbytes(shape_proxy(kv), rate)
+        assert est == m.estimate_nbytes(kv, rate)
+        assert est == m.compress(kv, rate).nbytes, rate
+
+
+def test_drop_kivi_pricing_copies_no_tokens():
+    """Pricing a full-width page's ladder from its shape proxy allocates
+    nothing token-sized (one copy of k would be 29 MB)."""
+    m = DropQuantCompression()
+    proxy = shape_proxy({"k": np.zeros((28, 256, 1024), np.float32),
+                         "v": np.zeros((28, 256, 1024), np.float32),
+                         "positions": np.arange(256, dtype=np.int32)})
+    tracemalloc.start()
+    try:
+        for rate in m.rates(proxy):
+            m.estimate_nbytes(proxy, rate)
+        _, peak = tracemalloc.get_traced_memory()
+    finally:
+        tracemalloc.stop()
+    assert peak < 1 << 20, peak
 
 
 def test_ssm_quant_roundtrip():
