@@ -4,10 +4,17 @@ reduction and the check against the plain reference.
 The system under test is ``ServingEngine.process``, built by
 ``serving/baselines.build_engine`` with the TPU v5e time model and a
 ``ModelRunner`` holding the cell's model at its published widths, weights
-drawn from the seed by ``weights.py``. The engine is a discrete-event
-simulator whose events run real work (prefill, decode ticks, KIVI
-compression, the DRAM and SSD tiers, the controller), so the wall time of
-``process`` is real while the times it reports are simulated.
+drawn from the seed by ``reference.make_flat``. The engine is a
+discrete-event simulator whose events run real work (prefill, decode
+ticks, KIVI compression, the DRAM and SSD tiers, the controller), so the
+wall time of ``process`` is real while the times it reports are
+simulated.
+
+The configuration's ``reference`` key names its architecture module
+(``arch/<name>.py``, interface in ``reference.py``): the program's
+``ModelConfig``, the weights' shapes and layout, the counts the readers
+use and the plain reference all come from it; nothing here knows an
+architecture.
 
 How a run measures:
 
@@ -44,7 +51,6 @@ from __future__ import annotations
 import contextlib
 import copy
 import gc
-import importlib.util
 import json
 import os
 import pathlib
@@ -59,11 +65,6 @@ import numpy as np
 ROOT = pathlib.Path(__file__).resolve().parents[1]
 BENCH = ROOT / "bench"
 
-# the tiny model of a CPU rehearsal (``--rehearse``): every layer of the
-# run at a size the CPU tests can hold
-REHEARSAL_DIMS = {"n_layers": 2, "d_model": 128, "n_heads": 4,
-                  "n_kv_heads": 2, "head_dim": 32, "d_ff": 256,
-                  "vocab": 1024}
 CHECK_TOKENS = 256          # served tokens the reference compares, at least
 CHECK_REQUESTS = 8          # and at most this many requests
 
@@ -89,8 +90,14 @@ def load_json(path: pathlib.Path) -> dict:
 
 def load_cell(name: str, rehearse: bool = False):
     """(benchmark, workload entry, configuration file, mix) for a cell of
-    ``BENCHMARK.json``. The mix is ``traffic/<traffic>.json``, with its
-    ``rehearsal`` sizes merged over it for a CPU rehearsal."""
+    ``BENCHMARK.json``. The mix is ``traffic/<traffic>.json`` with the
+    configuration's ``engine`` (how many lanes one chip holds for this
+    model) merged over its ``engine``, then, for a CPU rehearsal, the
+    mix's ``rehearsal`` sizes over that. A configuration that says what
+    its architecture module does not model, or whose lanes are longer than
+    its positions, is refused here, before any weight is drawn
+    (``reference.check_config``)."""
+    from bench import reference
     bench = load_json(ROOT / "BENCHMARK.json")
     cells = {w["name"]: w for w in bench["workloads"]}
     if name not in cells:
@@ -98,31 +105,29 @@ def load_cell(name: str, rehearse: bool = False):
     cell = cells[name]
     conf = next(c for c in bench["configs"] if c["name"] == cell["config"])
     cfg = load_json(ROOT / conf["file"])
+    if "reference" not in cfg:
+        raise ValueError(f"{conf['file']}: no 'reference' key naming its "
+                         f"architecture module")
+    reference.check_config(cfg, reference.load(cfg["reference"]),
+                           conf["file"])
     mix = load_json(BENCH / "traffic" / f"{cell['traffic']}.json")
+    mix = merge(mix, {"engine": cfg.get("engine", {})})
+    positions = cfg.get("max_position_embeddings")
+    if positions is not None and mix["engine"]["capacity"] > positions:
+        raise ValueError(f"{conf['file']}: lanes of "
+                         f"{mix['engine']['capacity']} positions exceed "
+                         f"max_position_embeddings {positions}")
     if rehearse:
         mix = merge(mix, mix.get("rehearsal", {}))
     return bench, cell, cfg, mix
 
 
-def model_dims(cfg: dict, rehearse: bool) -> dict:
+def architecture(cfg: dict, rehearse: bool):
+    """(architecture module, sizes) of a configuration, at the module's
+    tiny rehearsal sizes for a CPU rehearsal."""
     from bench import reference
-    dims = reference.dims_from_config(cfg)
-    if rehearse:
-        dims = dict(dims, **REHEARSAL_DIMS)
-    return dims
-
-
-def program_config(name: str, dims: dict):
-    """The program's ``ModelConfig`` for these sizes (dense GQA, bf16)."""
-    from repro.configs.base import ModelConfig
-    return ModelConfig(
-        name=name, family="dense", n_layers=dims["n_layers"],
-        d_model=dims["d_model"], n_heads=dims["n_heads"],
-        n_kv_heads=dims["n_kv_heads"], d_ff=dims["d_ff"],
-        vocab_size=dims["vocab"], head_dim=dims["head_dim"],
-        qk_norm=dims["qk_norm"], rope_theta=dims["rope_theta"],
-        norm_eps=dims["norm_eps"], tie_embeddings=True,
-        dtype="bfloat16", param_dtype="bfloat16")
+    arch = reference.load(cfg["reference"])
+    return arch, arch.dims_from_config(cfg, rehearse)
 
 
 # ---------------------------------------------------------------------------
@@ -361,12 +366,13 @@ def to_requests(reqs, task: str):
                     max_new_tokens=r.answer_tokens) for r in reqs]
 
 
-def warm_kivi(dims: dict, page: int) -> None:
+def warm_kivi(plane: tuple, page: int) -> None:
     """Compile the KIVI kernels at the page shape for every bit width, in
-    both directions, through the program's compression method."""
+    both directions, through the program's compression method; ``plane``
+    is the architecture's (layers, features) of a K or V page."""
     from repro.core.compression.kivi import BITS_LADDER, KIVICompression
-    f = dims["n_kv_heads"] * dims["head_dim"]
-    z = np.zeros((dims["n_layers"], page, f), np.float32)
+    layers, f = plane
+    z = np.zeros((layers, page, f), np.float32)
     kv = {"k": z, "v": z, "positions": np.arange(page, dtype=np.int32)}
     m = KIVICompression()
     for bits in BITS_LADDER:
@@ -389,15 +395,14 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     from repro.serving.runner import ModelRunner
     from bench import reference
     from bench import traffic as traffic_mod
-    from bench import weights
 
     if not rehearse:
         log(f"compile cache: {serve.enable_compile_cache()}")
         jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
         jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
     meter = CompileMeter.get()
-    dims = model_dims(cfg_json, rehearse)
-    cfg = program_config(cell["config"], dims)
+    arch, dims = architecture(cfg_json, rehearse)
+    cfg = arch.program_config(cell["config"], dims)
     eng = mix["engine"]
     gen = traffic_mod.Traffic(mix, seed, dims["vocab"])
     dev = jax.devices()[0]
@@ -413,8 +418,8 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
         f"{len(gen.docs) or 'unique'} documents, prompt lengths "
         f"{gen.prompt_lengths()}")
 
-    flat = weights.make_flat(dims, seed)
-    params = weights.program_layout(flat)
+    flat = reference.make_flat(arch, dims, seed)
+    params = arch.program_layout(flat)
     jax.block_until_ready(params)
     log_memory("after the weights")
     model = build_model(cfg)
@@ -435,7 +440,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
                 return make_rig(runner, docs, mix, cfg, n_active,
                                 os.path.join(spool_root, f"rig{n_rig}"))
 
-            warm_kivi(dims, eng["page_tokens"])
+            warm_kivi(arch.kv_plane(dims), eng["page_tokens"])
             hooks.probe("warm_kivi")
             if gen.kind == "documents":
                 rig = new_rig(gen.docs)
@@ -533,9 +538,9 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
                     e2e_names(bench, cell["name"]), setup_s)
     if trace:
         try:
-            out["per_layer"] = per_layer(bench, cell, dims, hooks, window,
-                                         trace_dir, dev, results, mix,
-                                         rehearse)
+            out["per_layer"] = per_layer(bench, cell, arch, dims, hooks,
+                                         window, trace_dir, dev, results,
+                                         mix, rehearse)
         finally:
             shutil.rmtree(trace_dir, ignore_errors=True)
 
@@ -543,10 +548,10 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     del runner, params, model
     gc.collect()
     sample = check_sample(results, gen, seed)
-    out["checks"] = check(sample, gen, dims, seed, mix, out)
+    out["checks"] = check(sample, gen, arch, dims, seed, mix, out)
     if control:
-        out["control_checks"] = check(sample, gen, dims, seed, mix, out,
-                                      gap_fn=reference.control_gaps)
+        out["control_checks"] = check(sample, gen, arch, dims, seed, mix,
+                                      out, gap_fn=reference.control_gaps)
     out.update(peak=peak, held=held, dims=dims, cell=cell)
     return out
 
@@ -609,14 +614,13 @@ def summarize(results, window, hooks: Hooks, gen, e2e: List[str],
             "window": window, "results": results}
 
 
-def per_layer(bench, cell, dims, hooks, window, trace_dir, dev, results,
-              mix, rehearse: bool) -> dict:
+def per_layer(bench, cell, arch, dims, hooks, window, trace_dir, dev,
+              results, mix, rehearse: bool) -> dict:
     """Every per-layer metric of ``BENCHMARK.json`` that applies to this
     cell, read by ``metrics/<name>.py``; a reader that finds nothing
     returns None and the metric is left out."""
     from bench import peaks as peaks_mod
     from bench import trace as tr
-    from bench import weights
     devices, spans = tr.load(tr.find_xplane(trace_dir),
                              host_as_device=rehearse)
     seg = [s for s in spans if s[0] == "segment"]
@@ -631,8 +635,9 @@ def per_layer(bench, cell, dims, hooks, window, trace_dir, dev, results,
     busy = np.mean([tr.busy_ns(v) for v in used.values()]) * 1e-9
     first = next(iter(used.values()))
     ctx = {
-        "dims": dims, "hooks": hooks, "window": window, "mix": mix,
-        "params": weights.param_count(dims),
+        "hooks": hooks, "window": window, "mix": mix,
+        "params": arch.param_count(dims),
+        "attn_width": arch.attn_width(dims),
         "peaks": peaks_mod.peaks("TPU v5 lite" if rehearse
                                  else dev.device_kind),
         "device_events": first, "host_spans": spans,
@@ -668,12 +673,9 @@ def e2e_names(bench: dict, cell_name: str) -> List[str]:
 
 
 def load_reader(name: str):
-    path = BENCH / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{name}",
-                                                  path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    from bench import reference
+    return reference.load_file(BENCH / "metrics" / f"{name}.py",
+                               f"bench_metric_{name}").read
 
 
 # ---------------------------------------------------------------------------
@@ -718,25 +720,27 @@ def passes(checks: List[dict]) -> bool:
     return all(c["value"] <= c["limit"] for c in checks)
 
 
-def check(sample, gen, dims, seed, mix, out, gap_fn=None) -> List[dict]:
+def check(sample, gen, arch, dims, seed, mix, out,
+          gap_fn=None) -> List[dict]:
     """The numbers compared, each beside its limit. ``gap_fn`` gives a
-    request's served-token gaps against the reference
+    request's served-token gaps against ``arch``'s reference
     (``reference.served_gaps``, the program's tokens); ``control.py``
     passes ``reference.control_gaps`` to put the fp8 control's tokens in
     the program's place, which the benchmark's own runs never do."""
-    from bench import reference, weights
+    from bench import reference
     gap_fn = gap_fn or reference.served_gaps
     limits = mix["limits"]
     checks = []
     if sample:
-        flat = weights.make_flat(dims, seed)
+        flat = reference.make_flat(arch, dims, seed)
         gaps = []
         pad = gen.longest_sequence()
         with reference_clock() as rt:
             for r, ctx_tokens in sample:
                 prompt = np.concatenate([ctx_tokens,
                                          gen.asked[r.req_id].question])
-                gaps.append(gap_fn(flat, dims, prompt, r.answer, pad_to=pad))
+                gaps.append(gap_fn(arch, flat, dims, prompt, r.answer,
+                                   pad_to=pad))
         del flat
         gap = reference.widest(gaps)
         served = int(sum(len(r.answer) for r, _ in sample))

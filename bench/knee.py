@@ -31,17 +31,18 @@ def sweep(workload: str, rates, segments: int, seed: int = 1,
           segment_requests: int = 0):
     import tempfile
     import numpy as np
-    from bench import harness, traffic, weights
+    from bench import harness, reference, traffic
     from repro.models import build_model
     from repro.serving.runner import ModelRunner
     _, cell, cfg_json, mix = harness.load_cell(workload)
-    full = harness.model_dims(cfg_json, False)
-    small = dict(full, **harness.REHEARSAL_DIMS)
-    full_cfg = harness.program_config(cell["config"], full)
-    n_active = weights.param_count(full)
-    model = build_model(harness.program_config(cell["config"], small))
-    runner = ModelRunner(model, weights.program_layout(
-        weights.make_flat(small, seed)), capacity=mix["engine"]["capacity"])
+    arch, full = harness.architecture(cfg_json, False)
+    _, small = harness.architecture(cfg_json, True)
+    full_cfg = arch.program_config(cell["config"], full)
+    n_active = arch.param_count(full)
+    model = build_model(arch.program_config(cell["config"], small))
+    runner = ModelRunner(model, arch.program_layout(
+        reference.make_flat(arch, small, seed)),
+        capacity=mix["engine"]["capacity"])
     rows = []
     with tempfile.TemporaryDirectory() as spool:
         for rate in rates:

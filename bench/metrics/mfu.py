@@ -2,14 +2,14 @@
 segment, in %: model FLOPs really computed there — 2 x parameters per
 token prefilled or decoded in an active lane, plus attention (causal
 prefill 2*H*hd*T^2 per layer; decode 4*H*hd*context per layer and
-token) — over the traced segment's length times the peak."""
+token; H*hd summed over layers is the architecture's ``attn_width``) —
+over the traced segment's length times the peak."""
 
 
 def read(ctx):
-    d = ctx["dims"]
     a, b = ctx["trace_wall"]
-    p = ctx["params"]          # the tied embedding counts once, as the head
-    hh = d["n_heads"] * d["head_dim"] * d["n_layers"]
+    p = ctx["params"]          # the architecture's ``param_count``
+    hh = ctx["attn_width"]     # and its ``attn_width``
     flops = 0.0
     for t0, _, n in ctx["hooks"].prefills:
         if a <= t0 <= b:

@@ -1,50 +1,158 @@
-"""Plain float32 reference of a dense GQA transformer, and its fp8 control.
+"""The plain reference, shared by every architecture, and the seam each
+architecture plugs into.
 
-This is the architecture's forward pass written out in ``jax.numpy``:
-token embedding, per layer an RMSNorm, grouped-query attention with
-per-head query/key RMSNorm where the configuration has it, rotary
-position embedding (rotate-half pairing), a causal softmax, the output
-projection and a SwiGLU feed-forward, then a final RMSNorm and logits
-against the tied embedding. It imports nothing of the program and has no
-cache, kernel or batching. Every matrix product runs at
-``precision="highest"`` in float32 so that the TPU does not round it to
-bfloat16.
+A configuration file (``configs/<name>.json``) names its architecture by
+its ``reference`` key; ``load`` loads ``arch/<reference>.py``. Everything
+that depends on the architecture lives in that one module, and nothing
+here or in the harness knows an architecture. A new architecture joins
+the benchmark as new files: its configuration, its module and its entries
+in ``BENCHMARK.json``. The module defines:
 
-It runs layer by layer (one compiled layer, called once per layer), with
-the sequence padded to a multiple of ``BUCKET`` (a cell pads every
-request to its longest, so that one shape compiles); causal masking makes
-the padding invisible to real positions.
-Logits are formed only at the positions asked for.
+* ``READS``: the configuration keys it reads; ``DESCRIPTIVE``: keys it
+  allows and ignores (the source's bookkeeping, so that a configuration
+  can be its source's ``config.json`` with keys added); ``ASSUMED``:
+  ``{key: value}`` it builds in. A file states each assumed key with that
+  value; one whose value is ``None`` or ``False`` (a feature that is off)
+  may be left out. ``check_config`` refuses any other key, a missing
+  assumed key and a contradicted value, before a weight is drawn.
+* ``dims_from_config(cfg, rehearse) -> dims``: a flat dict of hashable
+  sizes with at least ``n_layers`` and ``vocab``; with ``rehearse``, tiny
+  sizes that a CPU rehearsal can hold, every layer kept.
+* ``program_config(name, dims)``: the program's ``ModelConfig``.
+* ``shapes(dims) -> {leaf: shape}`` and ``init(key, leaf, shape)``, one
+  leaf in float32: ``make_flat`` draws every leaf from the seed in one
+  jitted call and casts it to bfloat16.
+* ``program_layout(flat)``: those arrays as the program's parameter tree.
+* ``param_count(dims)``: parameters a token passes through (the model
+  FLOPs per token are twice it); ``attn_width(dims)``: query heads x head
+  size summed over layers, for attention FLOPs; ``kv_plane(dims)``:
+  (layers, features) of the K and V pages the program stores.
+* ``logits_at(flat, dims, tokens, rows, mode, pad_to)``: float32 logits
+  at positions ``rows`` of the causal forward pass over ``tokens``,
+  written in ``jax.numpy`` with the helpers below; it imports nothing of
+  the program and has no cache, kernel or batching. Every matrix product
+  goes through ``mm``, which runs at ``precision="highest"`` so that the
+  TPU does not round it to bfloat16, and in ``mode="fp8"`` rounds both
+  operands to float8 e4m3 first: the control, the step below the
+  bfloat16 the configurations state. The sequence is padded by
+  ``padded_tokens``; causal masking makes the padding invisible to real
+  positions.
 
-``mode="fp8"`` is the control: the same computation with both operands of
-every matrix product rounded to float8 e4m3 first, the step below the
-bfloat16 the configurations state. A served path that computed so would
-have to fail the comparison.
+Here: ``served_gaps``, ``control_gaps`` and ``widest``, written over the
+module's ``logits_at``; the weights' seed; the module loader and the
+configuration check.
 """
 from __future__ import annotations
 
 import functools
+import importlib.util
+import pathlib
+import sys
 from typing import List, Sequence
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+ARCH = pathlib.Path(__file__).resolve().parent / "arch"
 BUCKET = 512
 Q_CHUNK = 512
+# configuration keys the benchmark itself reads, whatever the architecture
+SHARED_KEYS = ("source", "notes", "reference", "max_position_embeddings",
+               "engine")
+# what a configuration's ``engine`` may set: how many lanes one chip holds
+ENGINE_KEYS = ("lanes",)
 
 
-def dims_from_config(cfg: dict) -> dict:
-    """Reference sizes from a configuration file (Hugging Face keys)."""
-    d = cfg["hidden_size"]
-    h = cfg["num_attention_heads"]
-    return {"n_layers": cfg["num_hidden_layers"], "d_model": d,
-            "n_heads": h, "n_kv_heads": cfg["num_key_value_heads"],
-            "head_dim": cfg.get("head_dim", d // h),
-            "d_ff": cfg["intermediate_size"], "vocab": cfg["vocab_size"],
-            "qk_norm": bool(cfg.get("qk_norm", False)),
-            "rope_theta": float(cfg.get("rope_theta", 10000.0)),
-            "norm_eps": float(cfg["rms_norm_eps"])}
+# ---------------------------------------------------------------------------
+# the seam
+# ---------------------------------------------------------------------------
+
+def load_file(path: pathlib.Path, key: str):
+    """The module in file ``path``, loaded once under the name ``key``."""
+    if key not in sys.modules:
+        if not path.is_file():
+            raise ValueError(f"no module {path}")
+        spec = importlib.util.spec_from_file_location(key, path)
+        mod = importlib.util.module_from_spec(spec)
+        spec.loader.exec_module(mod)
+        sys.modules[key] = mod
+    return sys.modules[key]
+
+
+def load(name: str, path: pathlib.Path = None):
+    """The architecture module of ``"reference": name``: ``arch/<name>.py``,
+    or the file ``path`` (a test's architecture)."""
+    return load_file(path or ARCH / f"{name}.py", f"bench_arch_{name}")
+
+
+def check_config(cfg: dict, arch, where: str) -> None:
+    """Refuse a configuration that says what ``arch`` does not model: a key
+    it neither reads nor lists, a value it assumes otherwise, or an assumed
+    key left out where leaving it out does not mean the feature is off."""
+    known = set(SHARED_KEYS) | set(arch.READS) | set(arch.DESCRIPTIVE) \
+        | set(arch.ASSUMED)
+    for key in cfg:
+        if key not in known:
+            raise ValueError(f"{where}: key {key!r} is not modelled by "
+                             f"architecture {cfg['reference']!r}")
+    for key, want in arch.ASSUMED.items():
+        if key not in cfg and want not in (None, False):
+            raise ValueError(f"{where}: {key!r} is missing; architecture "
+                             f"{cfg['reference']!r} assumes {want!r}")
+        if key in cfg and cfg[key] != want:
+            raise ValueError(f"{where}: {key!r} is {cfg[key]!r}, but "
+                             f"architecture {cfg['reference']!r} assumes "
+                             f"{want!r}")
+    for key, v in cfg.get("engine", {}).items():
+        if key not in ENGINE_KEYS:
+            raise ValueError(f"{where}: engine key {key!r} is not one a "
+                             f"configuration may set: {ENGINE_KEYS}")
+        if not isinstance(v, int) or isinstance(v, bool) or v <= 0:
+            raise ValueError(f"{where}: engine {key!r} is {v!r}, not a "
+                             f"positive whole number")
+
+
+# ---------------------------------------------------------------------------
+# weights
+# ---------------------------------------------------------------------------
+
+def seed_key(seed: int) -> jax.Array:
+    """A threefry key from any whole-number seed (64 bits are fine)."""
+    words = np.random.SeedSequence(int(seed)).generate_state(2)
+    return jax.random.wrap_key_data(np.asarray(words, np.uint32),
+                                    impl="threefry2x32")
+
+
+def _draw(arch, key, name: str, shape) -> jax.Array:
+    k = jax.random.fold_in(key, sum(map(ord, name)) * 7919 + len(name))
+    return arch.init(k, name, shape).astype(jnp.bfloat16)
+
+
+@functools.lru_cache(maxsize=None)
+def _maker(arch, dims_items: tuple):
+    shp = arch.shapes(dict(dims_items))
+    return jax.jit(lambda key: {n: _draw(arch, key, n, s)
+                                for n, s in shp.items()})
+
+
+def make_flat(arch, dims: dict, seed: int) -> dict:
+    """Every parameter of ``arch`` in bfloat16, keyed by leaf name, from
+    one jitted call on the device."""
+    return _maker(arch, tuple(sorted(dims.items())))(seed_key(seed))
+
+
+# ---------------------------------------------------------------------------
+# helpers of the forward passes
+# ---------------------------------------------------------------------------
+
+def padded_tokens(tokens: np.ndarray, pad_to: int = 0) -> np.ndarray:
+    """``tokens`` padded with zeros to ``pad_to`` (at least its own length)
+    rounded up to ``BUCKET``, so that one shape serves a whole cell."""
+    t = len(tokens)
+    tok = np.zeros(-(-max(t, pad_to) // BUCKET) * BUCKET, np.int32)
+    tok[:t] = tokens
+    return tok
 
 
 def _round(x, mode: str):
@@ -53,18 +161,20 @@ def _round(x, mode: str):
     return x
 
 
-def _mm(a, b, mode: str, spec: str = None):
+def mm(a, b, mode: str, spec: str = None):
+    """A matrix product at ``precision="highest"``, of operands rounded to
+    float8 e4m3 first where ``mode`` is ``"fp8"``."""
     a, b = _round(a, mode), _round(b, mode)
     if spec is None:
         return jnp.matmul(a, b, precision="highest")
     return jnp.einsum(spec, a, b, precision="highest")
 
 
-def _rms(x, w, eps):
+def rms(x, w, eps):
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * w
 
 
-def _rope(x, theta):
+def rope(x, theta):
     """x: (S, H, D) with positions 0..S-1; rotate-half pairing."""
     s, _, d = x.shape
     inv = 1.0 / (theta ** (jnp.arange(0, d, 2, dtype=jnp.float32) / d))
@@ -74,72 +184,18 @@ def _rope(x, theta):
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
 
 
-@functools.partial(jax.jit, static_argnames=("dims_items", "mode"))
-def _layer(x, w, dims_items, mode):
-    dm = dict(dims_items)
-    s = x.shape[0]
-    h, kv, hd, eps = (dm["n_heads"], dm["n_kv_heads"], dm["head_dim"],
-                      dm["norm_eps"])
-    w = jax.tree.map(lambda a: a.astype(jnp.float32), w)
-    a = _rms(x, w["ln1"], eps)
-    q = _mm(a, w["wq"], mode).reshape(s, h, hd)
-    k = _mm(a, w["wk"], mode).reshape(s, kv, hd)
-    v = _mm(a, w["wv"], mode).reshape(s, kv, hd)
-    if dm["qk_norm"]:
-        q = _rms(q, w["q_norm"], eps)
-        k = _rms(k, w["k_norm"], eps)
-    q = _rope(q, dm["rope_theta"])
-    k = _rope(k, dm["rope_theta"])
-    g = h // kv
-    qg = q.reshape(s, kv, g, hd)
-    outs = []
-    for c0 in range(0, s, Q_CHUNK):
-        qc = qg[c0:c0 + Q_CHUNK]
-        n = qc.shape[0]
-        sc = _mm(qc, k, mode, "qkgd,tkd->kgqt") * hd ** -0.5
-        qi = c0 + jnp.arange(n)[:, None]
-        sc = jnp.where(jnp.arange(s)[None, :] <= qi, sc, -jnp.inf)
-        p = jax.nn.softmax(sc, axis=-1)
-        outs.append(_mm(p, v, mode, "kgqt,tkd->qkgd").reshape(n, h * hd))
-    o = jnp.concatenate(outs, axis=0)
-    x = x + _mm(o, w["wo"], mode)
-    b = _rms(x, w["ln2"], eps)
-    ff = jax.nn.silu(_mm(b, w["wi_gate"], mode)) * _mm(b, w["wi_up"], mode)
-    return x + _mm(ff, w["w_down"], mode)
+# ---------------------------------------------------------------------------
+# the numbers compared
+# ---------------------------------------------------------------------------
+
+def _forced(prompt, served):
+    seq = np.concatenate([np.asarray(prompt, np.int64),
+                          np.asarray(served[:-1], np.int64)])
+    rows = list(range(len(prompt) - 1, len(prompt) - 1 + len(served)))
+    return seq, rows
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "mode"))
-def _head(x_rows, final_norm, embed, eps, mode):
-    y = _rms(x_rows, final_norm.astype(jnp.float32), eps)
-    return _mm(y, embed.astype(jnp.float32).T, mode)
-
-
-LAYER_LEAVES = ("ln1", "ln2", "wq", "wk", "wv", "wo", "wi_gate", "wi_up",
-                "w_down", "q_norm", "k_norm")
-
-
-def logits_at(flat: dict, dims: dict, tokens: np.ndarray,
-              rows: Sequence[int], mode: str = "fp32",
-              pad_to: int = 0) -> np.ndarray:
-    """float32 logits at positions ``rows`` of the causal forward pass over
-    ``tokens``; ``flat`` is the weights by leaf name (``weights.make_flat``).
-    The sequence is padded to ``pad_to`` (at least its own length rounded
-    up to ``BUCKET``), so that one shape serves a whole cell."""
-    t = len(tokens)
-    padded = -(-max(t, pad_to) // BUCKET) * BUCKET
-    tok = np.zeros(padded, np.int32)
-    tok[:t] = tokens
-    x = flat["embed"][jnp.asarray(tok)].astype(jnp.float32)
-    items = tuple(sorted(dims.items()))
-    for li in range(dims["n_layers"]):
-        w = {n: flat[n][li] for n in LAYER_LEAVES if n in flat}
-        x = _layer(x, w, items, mode)
-    xr = x[jnp.asarray(np.asarray(rows, np.int32))]
-    return np.asarray(_head(xr, flat["final_norm"], flat["embed"],
-                            dims["norm_eps"], mode))
-
-
-def served_gaps(flat: dict, dims: dict, prompt: np.ndarray,
+def served_gaps(arch, flat: dict, dims: dict, prompt: np.ndarray,
                 served: Sequence[int], mode: str = "fp32",
                 pad_to: int = 0) -> np.ndarray:
     """Per served token, how far its reference logit lies below the
@@ -148,23 +204,19 @@ def served_gaps(flat: dict, dims: dict, prompt: np.ndarray,
     ``prompt`` is the context and question, ``served`` the answer tokens
     the program produced after it; the reference is teacher-forced on
     both."""
-    seq = np.concatenate([np.asarray(prompt, np.int64),
-                          np.asarray(served[:-1], np.int64)])
-    rows = list(range(len(prompt) - 1, len(prompt) - 1 + len(served)))
-    lg = logits_at(flat, dims, seq, rows, mode, pad_to)
+    seq, rows = _forced(prompt, served)
+    lg = arch.logits_at(flat, dims, seq, rows, mode, pad_to)
     return lg.max(axis=-1) - lg[np.arange(len(served)), np.asarray(served)]
 
 
-def control_gaps(flat: dict, dims: dict, prompt: np.ndarray,
+def control_gaps(arch, flat: dict, dims: dict, prompt: np.ndarray,
                  served: Sequence[int], pad_to: int = 0) -> np.ndarray:
     """The fp8 control at the same prompts and tokens: per position, how
     far the reference's logit of the token fp8 puts first lies below the
     reference's best."""
-    seq = np.concatenate([np.asarray(prompt, np.int64),
-                          np.asarray(served[:-1], np.int64)])
-    rows = list(range(len(prompt) - 1, len(prompt) - 1 + len(served)))
-    ref = logits_at(flat, dims, seq, rows, "fp32", pad_to)
-    low = logits_at(flat, dims, seq, rows, "fp8", pad_to)
+    seq, rows = _forced(prompt, served)
+    ref = arch.logits_at(flat, dims, seq, rows, "fp32", pad_to)
+    low = arch.logits_at(flat, dims, seq, rows, "fp8", pad_to)
     top = low.argmax(axis=-1)
     return ref.max(axis=-1) - ref[np.arange(len(served)), top]
 

@@ -4,7 +4,9 @@ fp8 control, at a size a test can hold, lies far above what the program
 reads, which is what lets one limit sit between them."""
 import numpy as np
 
-from bench import harness, reference, weights
+from bench import harness, reference
+
+DENSE = reference.load("dense_gqa")
 
 
 def test_altered_token_fails_the_check(monkeypatch):
@@ -44,9 +46,9 @@ def test_fp8_control_fails_the_check():
     assert ctrl["limit"] == prog["limit"] and ctrl["value"] > ctrl["limit"]
 
 
-DIMS = dict(n_layers=2, d_model=128, n_heads=4, n_kv_heads=2, head_dim=32,
-            d_ff=256, vocab=1024, qk_norm=True, rope_theta=1e6,
-            norm_eps=1e-6)
+# qwen3-1.7b's configuration at the rehearsal's sizes
+DIMS = harness.architecture(harness.load_json(
+    harness.BENCH / "configs" / "qwen3-1.7b.json"), True)[1]
 
 
 def test_reference_matches_the_program_in_float32():
@@ -56,16 +58,16 @@ def test_reference_matches_the_program_in_float32():
     import jax
     import jax.numpy as jnp
     from repro.models import build_model
-    flat = weights.make_flat(DIMS, 3)
-    cfg = dataclasses.replace(harness.program_config("t", DIMS),
+    flat = reference.make_flat(DENSE, DIMS, 3)
+    cfg = dataclasses.replace(DENSE.program_config("t", DIMS),
                               dtype="float32", param_dtype="float32")
     params = jax.tree.map(lambda a: a.astype(jnp.float32),
-                          weights.program_layout(flat))
+                          DENSE.program_layout(flat))
     toks = np.random.default_rng(0).integers(8, 1000, 40).astype(np.int32)
     with jax.default_matmul_precision("highest"):
         prog = np.asarray(build_model(cfg).forward(params,
                                                    {"tokens": toks[None]}))
-    ref = reference.logits_at(flat, DIMS, toks, list(range(40)))
+    ref = DENSE.logits_at(flat, DIMS, toks, list(range(40)))
     assert np.abs(prog[0] - ref).max() <= 1e-4 * np.abs(ref).max()
 
 
@@ -76,9 +78,9 @@ def test_fp8_control_lies_far_above_bf16():
     import jax
     import jax.numpy as jnp
     from repro.models import build_model
-    flat = weights.make_flat(DIMS, 4)
-    cfg = harness.program_config("t", DIMS)
-    params = weights.program_layout(flat)
+    flat = reference.make_flat(DENSE, DIMS, 4)
+    cfg = DENSE.program_config("t", DIMS)
+    params = DENSE.program_layout(flat)
     model = build_model(cfg)
     fwd = jax.jit(lambda t: model.forward(params, {"tokens": t}))
     rng = np.random.default_rng(1)
@@ -92,6 +94,8 @@ def test_fp8_control_lies_far_above_bf16():
             lg = np.asarray(fwd(jnp.asarray(pad)), np.float32)
             seq.append(int(lg[0, len(seq) - 1].argmax()))
         prompt, served = np.asarray(seq[:n_prompt]), seq[n_prompt:]
-        prog_gaps.append(reference.served_gaps(flat, DIMS, prompt, served))
-        ctrl_gaps.append(reference.control_gaps(flat, DIMS, prompt, served))
+        prog_gaps.append(reference.served_gaps(DENSE, flat, DIMS, prompt,
+                                               served))
+        ctrl_gaps.append(reference.control_gaps(DENSE, flat, DIMS, prompt,
+                                                served))
     assert reference.widest(ctrl_gaps) > 3 * reference.widest(prog_gaps)

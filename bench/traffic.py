@@ -1,8 +1,10 @@
 """The one traffic generator: turns a mix's data file and a seed into
 documents and request segments.
 
-A mix (``traffic/<name>.json``) gives sizes, not code; a cell that needs
-other sizes (fewer lanes for a heavier model) names a mix of its own:
+A mix (``traffic/<name>.json``) gives sizes, not code; a heavier model
+that fits fewer lanes on a chip says so in its configuration's
+``engine.lanes``, which the harness merges over the mix's, and keeps the
+mix's traffic:
 
 * ``generator: "documents"`` — a fixed library of ``documents.count``
   documents asked again and again. Their lengths are the quantiles of a
