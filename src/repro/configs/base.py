@@ -13,6 +13,7 @@ from __future__ import annotations
 
 import dataclasses
 import enum
+import math
 from typing import Optional, Sequence, Tuple
 
 
@@ -86,6 +87,12 @@ class ModelConfig:
     norm_eps: float = 1e-5
     tie_embeddings: bool = False
 
+    # muP scalings (MiniCPM), named as in its config.json; None = off, and
+    # an off scaling adds no operation to the traced model
+    scale_emb: Optional[float] = None       # token embeddings x scale_emb
+    scale_depth: Optional[float] = None     # residual branches x this/sqrt(L)
+    dim_model_base: Optional[int] = None    # final hidden / (d_model/this)
+
     moe: Optional[MoEConfig] = None
     mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
@@ -115,6 +122,20 @@ class ModelConfig:
     @property
     def resolved_head_dim(self) -> int:
         return self.head_dim or self.d_model // self.n_heads
+
+    @property
+    def residual_scale(self) -> Optional[float]:
+        """Factor of each residual branch before its add (None = 1)."""
+        if self.scale_depth is None:
+            return None
+        return self.scale_depth / math.sqrt(self.n_layers)
+
+    @property
+    def head_divisor(self) -> Optional[float]:
+        """Divisor of the final normed hidden state before the head."""
+        if self.dim_model_base is None:
+            return None
+        return self.d_model / self.dim_model_base
 
     @property
     def d_inner(self) -> int:

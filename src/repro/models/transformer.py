@@ -202,7 +202,7 @@ def _apply_block(bp: Params, cfg: ModelConfig, kind: LayerKind, x, positions,
                 bp["attn"], cfg, h, positions, cache=cj,
                 cur_index=cur_index)
         new_cache["self"] = ac
-    x = x + out
+    x = x + _scaled(out, cfg.residual_scale)
     x = constrain(x, ("data", None, None))
 
     if "cross" in bp and enc_out is not None or (
@@ -228,7 +228,7 @@ def _apply_block(bp: Params, cfg: ModelConfig, kind: LayerKind, x, positions,
                                 dropless=moe_dropless or decode)
         else:
             out = mlp_fwd(bp["ffn"], h)
-        x = x + out
+        x = x + _scaled(out, cfg.residual_scale)
         x = constrain(x, ("data", None, None))
     return x, new_cache, aux
 
@@ -306,9 +306,30 @@ def encode(params: Params, cfg: ModelConfig, frames: jax.Array) -> jax.Array:
 # embeddings and heads
 # ---------------------------------------------------------------------------
 
+def _scaled(x: jax.Array, factor: Optional[float]) -> jax.Array:
+    """``x * factor``; ``x`` itself where the scaling is off (None), so
+    that an off scaling adds no operation to the trace."""
+    return x if factor is None else x * factor
+
+
+def embed_tokens(params: Params, cfg: ModelConfig, tokens: jax.Array):
+    """Token embeddings in the compute dtype, times ``scale_emb``: the one
+    lookup of prefill, training and decode."""
+    x = params["embed"][tokens].astype(_dtype(cfg.dtype))
+    return _scaled(x, cfg.scale_emb)
+
+
+def final_hidden(params: Params, cfg: ModelConfig, x: jax.Array):
+    """The last layer's output as the head reads it: the final RMSNorm,
+    then divided by ``d_model / dim_model_base``."""
+    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    div = cfg.head_divisor
+    return x if div is None else x / div
+
+
 def embed_inputs(params: Params, cfg: ModelConfig, batch: Dict[str, jax.Array]):
     dtype = _dtype(cfg.dtype)
-    tok = params["embed"][batch["tokens"]].astype(dtype)
+    tok = embed_tokens(params, cfg, batch["tokens"])
     if cfg.n_patches and "patch_embeds" in batch:
         patches = batch["patch_embeds"].astype(dtype) @ params["patch_proj"]
         tok = jnp.concatenate([patches, tok], axis=1)
@@ -316,7 +337,7 @@ def embed_inputs(params: Params, cfg: ModelConfig, batch: Dict[str, jax.Array]):
 
 
 def lm_logits(params: Params, cfg: ModelConfig, x: jax.Array) -> jax.Array:
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    x = final_hidden(params, cfg, x)
     head = params["embed"].T if cfg.tie_embeddings else params["lm_head"]
     logits = x @ head
     return constrain(logits, ("data", None, "model"))
@@ -356,7 +377,7 @@ def chunked_ce_loss(params: Params, cfg: ModelConfig, x: jax.Array,
     computes one (B, chunk, V) logits block, reduces it to (nll_sum, count),
     and frees it — peak logits memory drops S/chunk-fold (the difference
     between fitting HBM and not for 1M-token batches x 50k-150k vocabs)."""
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    x = final_hidden(params, cfg, x)
     head = _head_matrix(params, cfg)
     b, s, d = x.shape
     chunk = min(chunk, s)
@@ -445,8 +466,7 @@ def decode_step(params: Params, cfg: ModelConfig, cache: Params,
     entry (StreamingLLM-compressed KV occupies slots [0, n_kept) while the
     new token's true position is the original sequence length).
     """
-    dtype = _dtype(cfg.dtype)
-    x = params["embed"][tokens].astype(dtype)
+    x = embed_tokens(params, cfg, tokens)
     b = tokens.shape[0]
     pos = cur_index if position is None else position
     if jnp.ndim(pos) == 0:

@@ -1150,6 +1150,9 @@ class ServingEngine:
                     rep.waiting.append(req)
                     note(now, "arrival", req_id=req.req_id, replica=rep.idx)
                     issue(rep, now)
+                    if rep.waiting and rep.waiting[-1] is req:
+                        # no lane was free: it waits until its dispatch
+                        mark("lane_wait", req_id=req.req_id)
                     maybe_prefetch(now, rep)
 
                 elif kind == EV_CHUNK_DONE:

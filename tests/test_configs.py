@@ -95,3 +95,14 @@ def test_kv_bytes_per_token():
     assert ds.kv_bytes_per_token() == 27 * (512 + 64) * 2
     q = get_config("qwen3-1.7b")
     assert q.kv_bytes_per_token() == 28 * 2 * 8 * 128 * 2
+
+
+def test_minicpm_mup_scalings():
+    c = get_config("minicpm-2b")
+    assert (c.scale_emb, c.scale_depth, c.dim_model_base) == (12, 1.4, 256)
+    assert (c.norm_eps, c.rope_theta, c.tie_embeddings) == (1e-5, 1e4, True)
+    assert c.residual_scale == pytest.approx(1.4 / 40 ** 0.5)
+    assert c.head_divisor == 9.0
+    assert c.kv_bytes_per_token() == 40 * 2 * 36 * 64 * 2 == 368_640
+    q = get_config("qwen3-1.7b")
+    assert q.residual_scale is None and q.head_divisor is None

@@ -142,3 +142,36 @@ def test_traced_process_is_bit_identical(runner, tmp_path):
             "first_token"} <= names
     firsts = [s[3]["req_id"] for s in spans if s[0] == "first_token"]
     assert sorted(firsts) == sorted(r.req_id for r in reqs)
+
+
+def test_lane_wait_marks_the_requests_no_lane_took(runner, tmp_path):
+    """Eight requests 1 ms apart on two lanes: a request whose arrival
+    finds no free lane is marked ``lane_wait`` in its arrival event, and
+    dispatched in a later event; one that found a lane is dispatched in
+    its arrival event and has no mark."""
+    rng = np.random.RandomState(5)
+    contexts = make_prefix_sharing_contexts(
+        rng, runner.model.cfg.vocab_size, n_docs=2, n_variants=2,
+        prefix_len=128, suffix_len=64, n_probes=2)
+    reqs = round_robin_requests(contexts, 8, 0.001, max_new_tokens=4)
+    _, spans, _ = _traced(
+        tmp_path / "trace",
+        lambda: _served(runner, tmp_path / "run", reqs, contexts))
+    events = [s for s in spans if s[0] == "event"]
+
+    def first(name, rid):
+        return next(s for s in spans if s[0] == name
+                    and s[3].get("req_id") == rid)
+
+    waited = set()
+    for r in reqs:
+        arrival, dispatch = first("arrival", r.req_id), \
+            first("dispatch", r.req_id)
+        ev = next(e for e in events if _inside(arrival, e))
+        marks = [s for s in spans if s[0] == "lane_wait"
+                 and s[3]["req_id"] == r.req_id]
+        assert len(marks) == (0 if _inside(dispatch, ev) else 1)
+        if marks:
+            waited.add(r.req_id)
+            assert _inside(marks[0], ev) and marks[0][1] < dispatch[1]
+    assert 0 < len(waited) < len(reqs)

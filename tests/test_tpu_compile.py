@@ -1,5 +1,6 @@
-"""The served Pallas kernels compile for a TPU v5e chip at qwen3-1.7b
-widths, with no chip attached: the TPU compiler is installed and compiles
+"""The served Pallas kernels compile for a TPU v5e chip at the KV planes
+of the benchmark's configurations (qwen3-1.7b: 28 layers x 1024
+features; minicpm-2b: 40 layers x 2304), with no chip attached: the TPU compiler is installed and compiles
 for a described ``v5e:2x2`` topology. Interpret-mode tests cannot see
 Mosaic's tiling rules; these do.
 
@@ -15,9 +16,16 @@ from repro.configs import get_config
 from repro.core.compression.kivi import _round_group
 from repro.kernels.kivi import kernel as kk
 
-CFG = get_config("qwen3-1.7b")
-KV_WIDTH = CFG.n_kv_heads * CFG.resolved_head_dim        # 1024
 GROUP = 64                                               # KIVICompression
+# (config, tokens of the entry compiled, id prefix): qwen3-1.7b's cases
+# keep their first ids; minicpm-2b's entry is one 256-token page
+PLANES = [("qwen3-1.7b", 512, ""), ("minicpm-2b", 256, "minicpm-2b-")]
+
+
+def _plane(name: str):
+    """(layers, K/V features per token) of a configuration."""
+    cfg = get_config(name)
+    return cfg.n_layers, cfg.n_kv_heads * cfg.resolved_head_dim
 
 
 @pytest.fixture(scope="module")
@@ -56,19 +64,27 @@ def _compile_roundtrip(sharding, rows: int, cols: int, bits: int,
     assert "tpu_custom_call" in compiled.as_text()
 
 
-@pytest.mark.parametrize("bits", [2, 4, 8])
-@pytest.mark.parametrize("style", ["k", "v"])
-def test_kivi_compiles_for_v5e(one_chip, bits, style):
-    """A 512-token entry of all 28 layers. K groups tokens per channel;
-    V groups channels per token, which ops.py runs as the transpose."""
-    rows = CFG.n_layers * 512
-    shape = (rows, KV_WIDTH) if style == "k" else (KV_WIDTH, rows)
+@pytest.mark.parametrize("name, tokens, style, bits", [
+    pytest.param(n, t, style, bits, id=f"{pre}{style}-{bits}")
+    for n, t, pre in PLANES for style in "kv" for bits in (2, 4, 8)])
+def test_kivi_compiles_for_v5e(one_chip, name, tokens, style, bits):
+    """An entry of all layers (qwen3-1.7b: 512 tokens x 28 layers;
+    minicpm-2b: a 256-token page x 40 layers). K groups tokens per
+    channel; V groups channels per token, which ops.py runs as the
+    transpose."""
+    layers, width = _plane(name)
+    rows = layers * tokens
+    shape = (rows, width) if style == "k" else (width, rows)
     _compile_roundtrip(one_chip, *shape, bits, GROUP)
 
 
-@pytest.mark.parametrize("bits", [2, 4, 8])
-def test_kivi_compiles_short_entry_group(one_chip, bits):
-    """A one-token remainder entry: 28 K rows, so the compressor picks a
-    group of 28 (not a multiple of 8 sublanes)."""
-    g = _round_group(min(GROUP, CFG.n_layers), bits)
-    _compile_roundtrip(one_chip, CFG.n_layers, KV_WIDTH, bits, g)
+@pytest.mark.parametrize("name, bits", [
+    pytest.param(n, bits, id=f"{pre}{bits}")
+    for n, _, pre in PLANES for bits in (2, 4, 8)])
+def test_kivi_compiles_short_entry_group(one_chip, name, bits):
+    """A one-token remainder entry: as many K rows as layers (28 or 40),
+    so the compressor picks a group of that many (not a multiple of 8
+    sublanes)."""
+    layers, width = _plane(name)
+    g = _round_group(min(GROUP, layers), bits)
+    _compile_roundtrip(one_chip, layers, width, bits, g)
