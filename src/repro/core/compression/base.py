@@ -13,9 +13,12 @@ from __future__ import annotations
 
 import abc
 import dataclasses
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+import functools
+from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
+
+from repro.runtime.spans import span
 
 KVData = Dict[str, np.ndarray]
 
@@ -80,6 +83,16 @@ class CompressionMethod(abc.ABC):
     def decompress(self, entry: CompressedEntry) -> KVData:
         ...
 
+    def decompress_device(self, entry: CompressedEntry,
+                          put: Callable[[np.ndarray], Any]) -> Dict[str, Any]:
+        """``decompress`` on the device: each array but ``positions`` as
+        its float32 ``(rows, features)`` matrix (an ``(L, T, F)`` array
+        as ``(L*T, F)``), with ``put`` sending host arrays to the device.
+        This default sends the host-decompressed arrays."""
+        return {name: put(a.reshape(-1, a.shape[-1]))
+                for name, a in self.decompress(entry).items()
+                if name != "positions"}
+
     @abc.abstractmethod
     def estimate_nbytes(self, kv: KVData, rate: float) -> int:
         """Analytic compressed size — no compression performed."""
@@ -107,3 +120,51 @@ class NoCompression(CompressionMethod):
 
     def estimate_nbytes(self, kv: KVData, rate: float) -> int:
         return kv_nbytes(kv)
+
+
+def decompressed_view(entry: CompressedEntry) -> KVData:
+    """Shapes of the entry after decompression, without decompressing.
+
+    For drop-based methods the kept-token count lives in the stored
+    arrays themselves; we reconstruct shape-only views cheaply."""
+    if entry.method in ("none", "streaming_llm"):
+        return dict(entry.arrays)
+    # kivi / drop_kivi: meta["shape"] holds decompressed shapes
+    meta_shape = entry.meta["kivi"]["shape"] if "kivi" in entry.meta \
+        else entry.meta["shape"]
+    out = {k: np.broadcast_to(np.zeros((), np.float32), s)
+           for k, s in meta_shape.items()}
+    if "positions" in entry.arrays:
+        out["positions"] = entry.arrays["positions"]
+    return out
+
+
+@dataclasses.dataclass(eq=False)
+class StoredKV:
+    """A fetched entry in its stored form, with the method that reads it.
+    The served path writes it into a lane from this form
+    (``decompress_device``); ``kv`` decompresses it on the host for every
+    other reader, once."""
+    entry: CompressedEntry
+    codec: CompressionMethod
+
+    @functools.cached_property
+    def kv(self) -> KVData:
+        with span("decompress", method=self.entry.method):
+            return self.codec.decompress(self.entry)
+
+    @property
+    def n_tokens(self) -> int:
+        """Token rows the entry keeps (0 for state alone)."""
+        view = decompressed_view(self.entry)
+        for name in ("k", "ckv"):
+            if name in view:
+                return int(view[name].shape[1])
+        return 0
+
+    @property
+    def device_nbytes(self) -> int:
+        """Bytes ``decompress_device`` sends for the lane: every stored
+        array but ``positions``, which stays on the host."""
+        return sum(a.nbytes for name, a in self.entry.arrays.items()
+                   if name != "positions")

@@ -8,8 +8,10 @@ SSM entries (no token axis) are quantized per-row-group — quant-only archs
 """
 from __future__ import annotations
 
+import functools
 from typing import Dict, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -102,35 +104,55 @@ class KIVICompression(CompressionMethod):
         return CompressedEntry(self.name, true_rate, arrays, meta)
 
     def decompress(self, entry: CompressedEntry) -> KVData:
-        from repro.kernels.kivi.ref import Quantized
-        out: KVData = {}
-        for name, shape in entry.meta["shape"].items():
-            axis = entry.meta["axis"][name]
-            g = entry.meta["group"][name]
-            bits = entry.meta["bits"]
-            packed = entry.arrays[f"{name}.packed"]
-            rows = int(np.prod(shape[:-1], dtype=np.int64))
-            f = shape[-1]
-            g = _round_group(g, bits)
-            # padded dims as stored
-            if axis == 0:
-                padded_dim = -(-rows // g) * g
-            else:
-                padded_dim = -(-f // g) * g
-            # the kernel's (rows, cols) of unpacked codes
-            p_rows, p_cols = packed.shape if axis == 0 else packed.shape[::-1]
-            with span("kivi_dequantize", rows=p_rows * (8 // bits),
-                      cols=p_cols, bits=bits, group=g):
-                qt = Quantized(jnp.asarray(packed),
-                               jnp.asarray(entry.arrays[f"{name}.scale"]),
-                               jnp.asarray(entry.arrays[f"{name}.zero"]),
-                               bits, g, axis, padded_dim)
-                mat = np.asarray(kivi_ops.dequantize(qt))
-            mat = mat[:rows, :f]                     # strip padding
-            out[name] = mat.reshape(shape).astype(entry.meta["dtype"][name])
+        out: KVData = {
+            name: np.asarray(mat).reshape(entry.meta["shape"][name])
+            .astype(entry.meta["dtype"][name])
+            for name, mat in self.decompress_device(entry,
+                                                    jnp.asarray).items()}
         if "positions" in entry.arrays:
             out["positions"] = entry.arrays["positions"]
         return out
+
+    def decompress_device(self, entry: CompressedEntry, put) -> Dict:
+        """The codes, scales and zeros go to the device; the dequantized
+        float32 stays there."""
+        out = {}
+        for name, shape in entry.meta["shape"].items():
+            rows = int(np.prod(shape[:-1], dtype=np.int64))
+            mat = self._dequantize(entry, name, put)
+            if mat.shape != (rows, shape[-1]):      # strip padding
+                mat = _strip(mat, rows, shape[-1])
+            out[name] = mat
+        return out
+
+    def _dequantize(self, entry: CompressedEntry, name: str, put):
+        """One array's float32 matrix on the device, with the padding
+        ``compress`` added; ``put`` sends each stored array there."""
+        from repro.kernels.kivi.ref import Quantized
+        shape = entry.meta["shape"][name]
+        axis = entry.meta["axis"][name]
+        bits = entry.meta["bits"]
+        g = _round_group(entry.meta["group"][name], bits)
+        packed = entry.arrays[f"{name}.packed"]
+        # padded dims as stored
+        if axis == 0:
+            rows = int(np.prod(shape[:-1], dtype=np.int64))
+            padded_dim = -(-rows // g) * g
+        else:
+            padded_dim = -(-shape[-1] // g) * g
+        # the kernel's (rows, cols) of unpacked codes
+        p_rows, p_cols = packed.shape if axis == 0 else packed.shape[::-1]
+        with span("kivi_dequantize", rows=p_rows * (8 // bits),
+                  cols=p_cols, bits=bits, group=g):
+            qt = Quantized(put(packed), put(entry.arrays[f"{name}.scale"]),
+                           put(entry.arrays[f"{name}.zero"]),
+                           bits, g, axis, padded_dim)
+            return kivi_ops.dequantize(qt)
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _strip(mat: jax.Array, rows: int, cols: int) -> jax.Array:
+    return mat[:rows, :cols]
 
 
 def _axis_for(name: str) -> int:

@@ -54,8 +54,14 @@ class DropQuantCompression(CompressionMethod):
                                 "keep": keep, "bits": bits})
 
     def decompress(self, entry: CompressedEntry) -> KVData:
-        inner = CompressedEntry("kivi", 0.0, entry.arrays, entry.meta["kivi"])
-        return self.kivi.decompress(inner)
+        return self.kivi.decompress(self._inner(entry))
+
+    def decompress_device(self, entry: CompressedEntry, put):
+        return self.kivi.decompress_device(self._inner(entry), put)
+
+    @staticmethod
+    def _inner(entry: CompressedEntry) -> CompressedEntry:
+        return CompressedEntry("kivi", 0.0, entry.arrays, entry.meta["kivi"])
 
     def estimate_nbytes(self, kv: KVData, rate: float) -> int:
         keep, bits = self._pick(kv, rate)

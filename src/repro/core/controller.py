@@ -51,7 +51,9 @@ import heapq
 import time
 from typing import Dict, List, Optional, Sequence, Tuple
 
-from repro.core.compression.base import KVData, kv_nbytes, kv_num_tokens
+from repro.core.compression.base import (
+    KVData, StoredKV, kv_nbytes, kv_num_tokens,
+)
 from repro.core.entry import EntryMeta
 from repro.core.estimator import (
     DelayProfile, FrequencyEstimator, QualityEstimator,
@@ -97,7 +99,7 @@ class Transfer:
 
 @dataclasses.dataclass
 class FetchResult:
-    kv: KVData
+    stored: StoredKV                 # the entry as its tier holds it
     tier: str
     method: str
     rate: float
@@ -117,6 +119,11 @@ class FetchResult:
     def total_delay_s(self) -> float:
         return self.load_delay_s + self.xlink_delay_s \
             + self.decompress_delay_s
+
+    @property
+    def kv(self) -> KVData:
+        """The entry decompressed on the host (on first read)."""
+        return self.stored.kv
 
 
 class AdaptCacheController:
@@ -264,7 +271,7 @@ class AdaptCacheController:
                 self.counters["misses"] += 1
                 return None
             tier = self.tiers[meta.tier]
-            kv, entry = self.executor.fetch(meta)
+            stored = self.executor.fetch(meta)
             load = tier.load_delay_s(meta.nbytes)
             dec = self.delay_profile.decompress_delay_s(meta.method,
                                                         meta.nbytes)
@@ -281,7 +288,7 @@ class AdaptCacheController:
             self.counters[f"hit_{meta.tier}"] += 1
             if remote:
                 self.counters["hit_remote"] += 1
-            return FetchResult(kv, meta.tier, meta.method, meta.rate,
+            return FetchResult(stored, meta.tier, meta.method, meta.rate,
                                load, dec, meta.nbytes, remote=remote,
                                xlink_delay_s=xlink,
                                orig_nbytes=meta.orig_bytes)

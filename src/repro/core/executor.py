@@ -7,16 +7,13 @@ policy can evaluate candidate states without touching stored bytes.
 from __future__ import annotations
 
 import itertools
-from typing import Dict, List, Optional, Tuple
-
-import numpy as np
+from typing import Dict, List, Optional
 
 from repro.core.compression.base import (
-    CompressedEntry, CompressionMethod, KVData, shape_proxy,
+    CompressionMethod, KVData, StoredKV, decompressed_view, shape_proxy,
 )
 from repro.core.entry import EntryMeta
 from repro.core.policy import Move, Placement
-from repro.runtime.spans import span
 from repro.storage.tier import Tier
 
 
@@ -103,35 +100,14 @@ class Executor:
         meta.nbytes = nb
         self._index_move(meta, old_tier)
         self._ledger_move(meta, old_tier, old_nb)
-        self.proxies[meta.key] = shape_proxy(self._decompressed_view(entry, m))
+        self.proxies[meta.key] = shape_proxy(decompressed_view(entry))
         return nb
 
-    def _decompressed_view(self, entry: CompressedEntry,
-                           m: CompressionMethod) -> KVData:
-        """Shapes of the entry after decompression, without decompressing.
-
-        For drop-based methods the kept-token count lives in the stored
-        arrays themselves; we reconstruct shape-only views cheaply."""
-        if entry.method == "none":
-            return dict(entry.arrays)
-        if entry.method == "streaming_llm":
-            return dict(entry.arrays)
-        # kivi / drop_kivi: meta["shape"] holds decompressed shapes
-        meta_shape = entry.meta["kivi"]["shape"] if "kivi" in entry.meta \
-            else entry.meta["shape"]
-        out = {k: np.broadcast_to(np.zeros((), np.float32), s)
-               for k, s in meta_shape.items()}
-        if "positions" in entry.arrays:
-            out["positions"] = entry.arrays["positions"]
-        return out
-
     # -- fetch ---------------------------------------------------------------
-    def fetch(self, meta: EntryMeta) -> Tuple[KVData, CompressedEntry]:
-        tier = self.tiers[meta.tier]
-        entry = tier.get(meta.key)
-        with span("decompress", method=meta.method):
-            kv = self.methods[meta.method].decompress(entry)
-        return kv, entry
+    def fetch(self, meta: EntryMeta) -> StoredKV:
+        """The entry as stored: reading a page decompresses nothing."""
+        return StoredKV(self.tiers[meta.tier].get(meta.key),
+                        self.methods[meta.method])
 
     # -- promotion (speculative prefetch) ------------------------------------
     def promote(self, meta: EntryMeta, dst_name: str) -> int:
@@ -194,7 +170,7 @@ class Executor:
             meta.nbytes = nb
             self._ledger_move(meta, meta.tier, old_nb)
             self.proxies[meta.key] = shape_proxy(
-                self._decompressed_view(new_entry, m))
+                decompressed_view(new_entry))
             self.stats["recompress"] += 1
             return None
 

@@ -21,7 +21,10 @@ and decompress prices) so the serving engine can book each page read on
 the owning tier's ``IOChannel`` — partial-prefix loads contend with
 write-back and prefetch traffic like every other byte movement. The
 synchronous ``total_delay_s`` sum is kept as a property for the
-serialized baseline and unit tests.
+serialized baseline and unit tests. Each ``PageFetch`` holds its page as
+the tier stores it (``StoredKV``): the lane write at admission
+decompresses it on the device, and ``FetchPlan.kv`` joins the run on the
+host only for callers that read it.
 
 Non-token arrays (SSM states) summarize the whole prefix and cannot be
 paged — they ride the sub-page remainder. By default the remainder is
@@ -42,7 +45,7 @@ from typing import List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from repro.core.compression.base import KVData
+from repro.core.compression.base import KVData, StoredKV
 from repro.core.controller import AdaptCacheController, FetchResult, Transfer
 from repro.core.estimator import QualityEstimator
 from repro.runtime.spans import span
@@ -149,7 +152,7 @@ class PageFetch:
     nbytes: int
     method: str
     rate: float
-    kv: KVData
+    stored: StoredKV                 # the page as its tier holds it
     remote: bool                     # owned by a sibling replica's DRAM
     xlink_delay_s: float
     decompress_delay_s: float
@@ -181,10 +184,10 @@ class FetchPlan:
     pages shrink). A matched remainder entry rides ``pages`` as the
     final ``PageFetch`` (it is booked on a tier channel like any page)
     and reports its source-token length in ``remainder_tokens``."""
-    pages: List[PageFetch]
+    pages: List[PageFetch]           # the matched pieces, in token order
     src_tokens: int
     n_tokens: int
-    kv: Optional[KVData]            # joined matched pages (decompressed)
+    joined: Optional[KVData]        # ``kv`` once read
     remainder_tokens: int = 0       # sub-page tail covered by a matched
     #                                 remainder entry (0: none matched)
     quality: float = 1.0            # composed run quality: per-piece
@@ -196,6 +199,15 @@ class FetchPlan:
     @property
     def n_pages(self) -> int:
         return len(self.pages)
+
+    @property
+    def kv(self) -> Optional[KVData]:
+        """The matched run decompressed and joined on the host, on first
+        read; the served path writes the pages from their stored form."""
+        if self.joined is None and self.pages:
+            with span("kv_join", pieces=len(self.pages)):
+                self.joined = join_kv([p.stored.kv for p in self.pages])
+        return self.joined
 
     @property
     def total_delay_s(self) -> float:
@@ -361,14 +373,12 @@ class PagedPrefixCache:
                 rem_hit=rem_tokens > 0, rem_key=rkey)
             if not fetched:
                 return FetchPlan([], 0, 0, None)
-            with span("kv_join", pieces=len(fetched)):
-                kv = join_kv([f.kv for _, f in fetched])
             # dropped pages shrink; count ACTUAL kept tokens
-            n_tokens = kv["k" if "k" in kv else "ckv"].shape[1]
+            n_tokens = sum(f.stored.n_tokens for _, f in fetched)
             n_page_hits = len(fetched) - (1 if rem_tokens else 0)
             last = len(fetched) - 1
-            pages = [PageFetch(key, f.tier, f.nbytes, f.method, f.rate, f.kv,
-                               f.remote, f.xlink_delay_s,
+            pages = [PageFetch(key, f.tier, f.nbytes, f.method, f.rate,
+                               f.stored, f.remote, f.xlink_delay_s,
                                f.decompress_delay_s, f.load_delay_s,
                                orig_nbytes=f.orig_nbytes,
                                n_tokens=(rem_tokens
@@ -377,7 +387,7 @@ class PagedPrefixCache:
                      for i, (key, f) in enumerate(fetched)]
             return FetchPlan(
                 pages, n_page_hits * self.page_tokens + rem_tokens, n_tokens,
-                kv, remainder_tokens=rem_tokens,
+                None, remainder_tokens=rem_tokens,
                 quality=self._compose_quality(fetched, rem_tokens))
 
     def _compose_quality(self, fetched: List[Tuple[str, FetchResult]],

@@ -134,7 +134,7 @@ from repro.configs.base import LayerKind
 from repro.core.controller import AdaptCacheController, SimClock, Transfer
 from repro.runtime.spans import mark, span
 from repro.serving.chunking import (
-    PagedPrefixCache, join_kv, page_keys, tail_kv,
+    PAGE_TOKENS, PagedPrefixCache, page_keys, tail_kv,
 )
 from repro.serving.metrics import percentile_summary, quality_score, safe_mean
 from repro.serving.runner import ModelRunner
@@ -495,7 +495,9 @@ class ServingEngine:
                                           self.runner.params, self.tm,
                                           n_slots=self.n_lanes,
                                           capacity=self.runner.capacity,
-                                          device=devices[i % len(devices)]))
+                                          device=devices[i % len(devices)],
+                                          page_tokens=(self.page_tokens
+                                                       or PAGE_TOKENS)))
             for i in range(self.n_replicas)]
         if self.chunk_tokens > 0:
             # unified compute: decode ticks and prefill chunks share ONE
@@ -992,14 +994,14 @@ class ServingEngine:
                         note(now, "readahead_cancel", key=k, run=rk)
             # a full page-run hit never touches the real-compute prefill:
             # the lane content comes entirely from the fetched pages
+            # the lane takes the pages as stored, then the prefilled tail
             if plan.n_pages == 0:
                 kv_final = self._prefill_kv(ctx)
-            elif suffix == 0:
-                kv_final = plan.kv
             else:
-                kv_final = join_kv([plan.kv,
-                                    tail_kv(self._prefill_kv(ctx),
-                                            plan.src_tokens)])
+                kv_final = [p.stored for p in plan.pages]
+                if suffix:
+                    kv_final.append(tail_kv(self._prefill_kv(ctx),
+                                            plan.src_tokens))
             if plan.n_pages:
                 pf_hit = False
                 for p in plan.pages:

@@ -30,22 +30,29 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import heapq
 import itertools
 import weakref
-from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
+from typing import (Any, Callable, Dict, List, Optional, Sequence, Tuple,
+                    Union)
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from repro.configs.base import AttnKind, LayerKind, ModelConfig
-from repro.core.compression.base import KVData
+from repro.core.compression.base import KVData, StoredKV
 from repro.models import Model
 from repro.runtime.spans import mark, span
+from repro.serving.chunking import PAGE_TOKENS
 from repro.serving.runner import _layer_cache_refs
 from repro.serving.timemodel import TimeModel
 from repro.serving.workload import Request
+
+# what a lane admits: a host KVData, or pieces in token order, each a
+# fetched page in its stored form or a host KVData
+LaneRun = Union[KVData, Sequence[Union[KVData, StoredKV]]]
 
 
 @dataclasses.dataclass
@@ -98,13 +105,107 @@ def _shared_decode(model: Model):
     return fn
 
 
+def _lane_layout(cache, cfg: ModelConfig) -> Tuple[tuple, ...]:
+    """Where each KVData array lands in the batched cache: one entry per
+    cache leaf, ``(section, block index, block key, name, stacked,
+    layers)``, with ``layers`` the leaf's layers as indices into the
+    array's leading axis (attention layers for k/v/ckv/krope, Mamba
+    layers for ssm/conv), in the leaf's group order."""
+    leaves: Dict[tuple, List[Tuple[int, int]]] = {}
+    ai = mi = 0
+    for _, kind, (sect, j, g) in _layer_cache_refs(cache, cfg):
+        if kind == LayerKind.MAMBA:
+            sub, names, idx = "mamba", ("ssm", "conv"), mi
+            mi += 1
+        else:
+            sub, idx = "self", ai
+            names = (("ckv", "krope") if cfg.attn_kind == AttnKind.MLA
+                     else ("k", "v"))
+            ai += 1
+        for name in names:
+            leaves.setdefault((sect, j, sub, name, g is not None),
+                              []).append((g or 0, idx))
+    return tuple(key + (tuple(i for _, i in sorted(refs)),)
+                 for key, refs in leaves.items())
+
+
+def _layers(x: jax.Array, layers: Tuple[int, ...]) -> jax.Array:
+    lo = layers[0]
+    if layers == tuple(range(lo, lo + len(layers))):
+        return x[lo:lo + len(layers)]
+    return x[np.asarray(layers)]
+
+
+@functools.partial(jax.jit, static_argnames=("layout",),
+                   donate_argnames=("cache",))
+def _write_window(cache, rows, state, lane, off, n, layout):
+    """Write one window of a piece into lane ``lane`` of the donated
+    cache, in place, cast to the cache's dtypes.
+
+    ``rows`` maps each token array to its ``(layers*W, F)`` window, whose
+    first ``n`` rows go to lane rows ``[off, off + n)``; rows past ``n``
+    leave the lane as it was. Where ``off + W`` passes the lane's end the
+    window starts earlier (``dynamic_update_slice`` would clamp its start)
+    and its rows shift to match. ``state`` maps each whole-lane array
+    (Mamba) to its ``(layers*R, F)`` matrix. ``off``, ``n`` and ``lane``
+    are traced: one compile serves every run of a window's shape."""
+    counts = collections.Counter()
+    for _, _, _, name, _, layers in layout:
+        counts[name] += len(layers)
+    for sect, j, sub, name, stacked, layers in layout:
+        blk = cache[sect][j][sub]
+        leaf = blk[name]
+        lead = (0,) if stacked else ()
+        if name in rows:
+            x = rows[name].reshape(counts[name], -1, rows[name].shape[-1])
+            w, cap = x.shape[1], leaf.shape[len(lead) + 1]
+            start = jnp.minimum(off, cap - w)
+            shift = off - start
+            r = jnp.arange(w)
+            keep = (r >= shift) & (r < shift + n)
+            x = jnp.roll(_layers(x, layers), shift, axis=1)
+            x = x.reshape(x.shape[:2] + leaf.shape[len(lead) + 2:])
+            x = jnp.expand_dims(x if stacked else x[0], len(lead))
+            rest = (0,) * (leaf.ndim - len(lead) - 2)
+            idx = lead + (lane, start) + rest
+            old = jax.lax.dynamic_slice(leaf, idx, x.shape)
+            keep = keep.reshape((1,) * (len(lead) + 1) + (w,)
+                                + (1,) * len(rest))
+            blk[name] = jax.lax.dynamic_update_slice(
+                leaf, jnp.where(keep, x.astype(leaf.dtype), old), idx)
+        elif name in state:
+            x = state[name].reshape((-1,) + leaf.shape[len(lead) + 1:])
+            x = _layers(x, layers)
+            x = jnp.expand_dims(x if stacked else x[0], len(lead))
+            idx = lead + (lane,) + (0,) * (leaf.ndim - len(lead) - 1)
+            blk[name] = jax.lax.dynamic_update_slice(
+                leaf, x.astype(leaf.dtype), idx)
+    return cache
+
+
+def _positions(arrays) -> int:
+    return len(arrays["positions"]) if "positions" in arrays else 0
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2))
+def _windows(x: jax.Array, layers: int, w: int) -> Tuple[jax.Array, ...]:
+    """``(layers*T, F)`` rows as the ``(layers*w, F)`` windows that cover
+    them, the last padded with zeros."""
+    x = x.reshape(layers, -1, x.shape[-1])
+    x = jnp.pad(x, ((0, 0), (0, -x.shape[1] % w), (0, 0)))
+    return tuple(x[:, i:i + w].reshape(-1, x.shape[-1])
+                 for i in range(0, x.shape[1], w))
+
+
 class ContinuousBatcher:
     """``device`` (default: JAX's default device) holds this batcher's
     params and lane cache, so every decode tick and lane write runs
-    there — one engine replica per chip."""
+    there — one engine replica per chip. ``page_tokens`` is the window a
+    lane write moves at a time: the page size of the runs it admits."""
 
     def __init__(self, model: Model, params, time_model: TimeModel,
-                 n_slots: int = 4, capacity: int = 1024, device=None):
+                 n_slots: int = 4, capacity: int = 1024, device=None,
+                 page_tokens: int = PAGE_TOKENS):
         self.model = model
         self.tm = time_model
         self.n_slots = n_slots
@@ -117,65 +218,137 @@ class ContinuousBatcher:
                 self.device)
         self.slots = [SlotState() for _ in range(n_slots)]
         self._decode = _shared_decode(model)
+        self.window = min(page_tokens, capacity)
+        self._layout = _lane_layout(self.cache, model.cfg)
+        # name -> (leaf dtype, layers, shape a layer's rows or state has
+        # in the lane); token arrays have sub-block "self"
+        self._arrays: Dict[str, Tuple[Any, int, Tuple[int, ...]]] = {}
+        self._tokens = set()
+        for sect, j, sub, name, stacked, layers in self._layout:
+            leaf = self.cache[sect][j][sub][name]
+            dt, n, _ = self._arrays.get(name, (leaf.dtype, 0, ()))
+            per = leaf.shape[(2 if stacked else 1) + (sub == "self"):]
+            self._arrays[name] = (dt, n + len(layers), per)
+            if sub == "self":
+                self._tokens.add(name)
+        # uncommitted arrays on the default device share their compiled
+        # programs with the program's other calls (the KIVI kernels that
+        # set-up compiles among them); a replica elsewhere commits its own
+        self._put = (jnp.asarray if self.device == jax.devices()[0]
+                     else functools.partial(jax.device_put,
+                                            device=self.device))
+        self._warm()
 
     # -- lane loading ---------------------------------------------------------
-    def _write_lane(self, lane: int, kv: KVData) -> int:
-        """Write a (decompressed) entry into cache lane ``lane``; returns
-        number of occupied slots.
+    def _warm(self) -> None:
+        """Compile the lane writer for a page window of a fetched page
+        (float32) and of a host piece (the cache's dtypes, with any
+        state); writes no row."""
+        for host in (False, True):
+            rows, state = {}, {}
+            for name, (dt, n, per) in self._arrays.items():
+                dt = dt if host else np.float32
+                if name in self._tokens:
+                    rows[name] = self._put(jnp.zeros(
+                        (n * self.window, int(np.prod(per))), dt))
+                elif host:
+                    state[name] = self._put(jnp.zeros(
+                        (n * int(np.prod(per[:-1])), per[-1]), dt))
+            self.cache = _write_window(
+                self.cache, rows, state, np.int32(0), np.int32(0),
+                np.int32(0), layout=self._layout)
 
-        Updates are per-leaf ``.at[...].set`` on the target lane only —
-        no host round-trip of the whole batched cache pytree (the seed
-        version copied every lane of every layer through numpy on each
-        admission, an O(whole-cache) transfer per request). The
-        ``lane_write`` span carries the bytes that cross from host to
-        device: each host array, cast on the host to the cache dtype.
-        """
-        cfg = self.model.cfg
-        n_kept = int(kv["positions"].shape[0]) if "positions" in kv else 0
-        ai = mi = 0
-        hd = cfg.resolved_head_dim
-        # (leaf dict, leaf name, value, group index, whole lane)
-        writes = []
-        for i, kind, (sect, j, g) in _layer_cache_refs(self.cache, cfg):
-            blk = self.cache[sect][j]
-            if kind == LayerKind.MAMBA:
-                writes += [(blk["mamba"], "ssm", kv["ssm"][mi], g, True),
-                           (blk["mamba"], "conv", kv["conv"][mi], g, True)]
-                mi += 1
-            elif cfg.attn_kind == AttnKind.MLA:
-                writes += [(blk["self"], "ckv", kv["ckv"][ai], g, False),
-                           (blk["self"], "krope", kv["krope"][ai], g, False)]
-                ai += 1
-            else:
-                writes += [(blk["self"], "k",
-                            kv["k"][ai].reshape(n_kept, -1, hd), g, False),
-                           (blk["self"], "v",
-                            kv["v"][ai].reshape(n_kept, -1, hd), g, False)]
-                ai += 1
-        h2d = sum(int(np.size(val)) * d[name].dtype.itemsize
-                  for d, name, val, _, _ in writes
-                  if not isinstance(val, jax.Array))
-        with span("lane_write", h2d_bytes=h2d):
-            for d, name, val, g, whole in writes:
-                # stored entries are float32; the lane cache holds the
-                # model dtype, so cast before the scatter
-                val = jnp.asarray(val, d[name].dtype)
-                n = val.shape[0]
-                if whole and g is not None:
-                    d[name] = d[name].at[g, lane].set(val)
-                elif whole:
-                    d[name] = d[name].at[lane].set(val)
-                elif g is not None:
-                    d[name] = d[name].at[g, lane, :n].set(val)
+    def _write_lane(self, lane: int, run: LaneRun) -> int:
+        """Write a run into cache lane ``lane``, piece after piece in
+        token order; returns the number of occupied slots.
+
+        ``run`` is a host KVData or a sequence of pieces, each a fetched
+        ``StoredKV`` or a host KVData (a prefill's arrays). A stored piece
+        goes to the device as stored (KIVI codes, scales and zeros, or
+        lossless float32 rows) and is decompressed there; a host piece is
+        cast on the host to the cache dtype. Either is written a window
+        of ``self.window`` rows at a time by one donated program. The
+        ``lane_write`` span carries the bytes that cross to the device
+        and the pieces of each kind."""
+        pieces = [run] if isinstance(run, dict) else list(run)
+        n_stored = sum(isinstance(p, StoredKV) for p in pieces)
+        h2d = sum(p.device_nbytes if isinstance(p, StoredKV)
+                  else sum(int(np.size(a)) * self._arrays[name][0].itemsize
+                           for name, a in p.items() if name in self._arrays)
+                  for p in pieces)
+        off = 0
+        with span("lane_write", h2d_bytes=h2d, pages=n_stored,
+                  host_pieces=len(pieces) - n_stored):
+            for p in pieces:
+                if isinstance(p, StoredKV):
+                    off = self._write_stored(lane, p, off)
                 else:
-                    d[name] = d[name].at[lane, :n].set(val)
-        return n_kept
+                    off = self._write_host(lane, p, off)
+        return off
+
+    def _write_stored(self, lane: int, p: StoredKV, off: int) -> int:
+        with span("decompress", method=p.entry.method):
+            mats = p.codec.decompress_device(p.entry, self._put)
+        t = p.n_tokens
+        w = self.window
+        wins = {}
+        for name, m in mats.items():
+            if name in self._tokens:
+                n = self._arrays[name][1]
+                wins[name] = ([m] if m.shape[0] == n * w
+                              else list(_windows(m, n, w)))
+        state = {name: m for name, m in mats.items()
+                 if name in self._arrays and name not in self._tokens}
+        return self._write_windows(lane, wins, state, off,
+                                   t if wins else _positions(p.entry.arrays))
+
+    def _write_host(self, lane: int, kv: KVData, off: int) -> int:
+        w = self.window
+        wins, state = {}, {}
+        t = 0
+        for name, a in kv.items():
+            if name not in self._arrays:
+                continue
+            dt, n, _ = self._arrays[name]
+            if name in self._tokens:
+                t = a.shape[1]
+                wins[name] = []
+                for lo in range(0, t, w):
+                    x = self._put(np.asarray(a[:, lo:lo + w], dt)
+                                  .reshape(-1, a.shape[-1]))
+                    wins[name].append(x if t - lo >= w
+                                      else _windows(x, n, w)[0])
+            else:
+                state[name] = self._put(
+                    np.asarray(a, dt).reshape(-1, a.shape[-1]))
+        return self._write_windows(lane, wins, state, off,
+                                   t if wins else _positions(kv))
+
+    def _write_windows(self, lane: int, wins: Dict[str, List[jax.Array]],
+                       state: Dict[str, jax.Array], off: int, t: int) -> int:
+        """Write a piece of ``t`` rows at lane row ``off``, one window per
+        call, the whole-lane state with each; returns the next row."""
+        if off + t > self.capacity:
+            raise ValueError(f"a run of {off + t} rows overflows a lane of "
+                             f"{self.capacity}")
+        w = self.window
+        if not t:
+            wins = {}
+        for i in range(-(-t // w) if wins else int(bool(state))):
+            self.cache = _write_window(
+                self.cache, {name: ws[i] for name, ws in wins.items()},
+                state, np.int32(lane), np.int32(off + i * w),
+                np.int32(min(w, t - i * w) if wins else 0),
+                layout=self._layout)
+        return off + t
 
     def free_lanes(self) -> List[int]:
         return [i for i, s in enumerate(self.slots) if not s.active]
 
-    def admit(self, lane: int, req: Request, kv: KVData, orig_len: int,
+    def admit(self, lane: int, req: Request, kv: LaneRun, orig_len: int,
               now: float, kv_frac: float = 1.0) -> None:
+        """Write ``kv`` (a host KVData, or a run of pieces: see
+        ``_write_lane``) into lane ``lane`` and start ``req`` there."""
         with span("admit", req_id=req.req_id):
             n_kept = self._write_lane(lane, kv)
             self.slots[lane] = SlotState(
@@ -422,7 +595,7 @@ class LaneSet:
         return (len(self.waiting) + len(self.reserved)
                 + sum(s.active for s in self.batcher.slots))
 
-    def admit(self, lane: int, req: Request, kv: KVData, orig_len: int,
+    def admit(self, lane: int, req: Request, kv: LaneRun, orig_len: int,
               now: float, kv_frac: float = 1.0) -> None:
         self.reserved.discard(lane)
         self.batcher.admit(lane, req, kv, orig_len, now, kv_frac=kv_frac)

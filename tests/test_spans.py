@@ -10,9 +10,12 @@ import pytest
 
 from bench import program_trace, trace as tr
 from repro.configs import get_config
+from repro.core.compression import KIVICompression, NoCompression
+from repro.core.compression.base import StoredKV
 from repro.models import build_model
 from repro.runtime.spans import PREFIX, mark, span
 from repro.serving.baselines import build_engine
+from repro.serving.chunking import split_kv, tail_kv
 from repro.serving.runner import ModelRunner
 from repro.serving.scheduler import ContinuousBatcher
 from repro.serving.timemodel import A100, TimeModel
@@ -110,7 +113,25 @@ def test_lane_write_counts_the_bytes_it_copies(runner, tmp_path):
     cache_dtype = jax.tree.leaves(b.cache)[0].dtype
     written = sum(np.asarray(kv[n], cache_dtype).nbytes for n in ("k", "v"))
     assert written > 0
-    assert write[3] == {"h2d_bytes": written}
+    assert write[3] == {"h2d_bytes": written, "pages": 0, "host_pieces": 1}
+
+    # fetched pages cross as stored (KIVI codes, scales and zeros; lossless
+    # float32 rows), never their positions; a prefilled tail as host rows
+    # cast to the cache dtype
+    pages, _ = split_kv(kv, 16)
+    run = [StoredKV(KIVICompression().compress(pages[0], 0.0, bits=4),
+                    KIVICompression()),
+           StoredKV(NoCompression().compress(pages[1], 1.0),
+                    NoCompression()),
+           tail_kv(kv, 32)]
+    _, spans, _ = _traced(tmp_path / "pages",
+                          lambda: b.admit(0, req, run, 40, 0.0))
+    write = next(s for s in spans if s[0] == "lane_write")
+    stored = sum(a.nbytes for p in run[:2] for n, a in p.entry.arrays.items()
+                 if n != "positions")
+    tail = sum(np.asarray(run[2][n], cache_dtype).nbytes for n in ("k", "v"))
+    assert write[3] == {"h2d_bytes": stored + tail, "pages": 2,
+                        "host_pieces": 1}
 
 
 def _served(runner, tmp, reqs, contexts):
@@ -136,7 +157,7 @@ def test_traced_process_is_bit_identical(runner, tmp_path):
     # the run went through every layer that carries a span
     names = {s[0] for s in spans}
     assert {"event", "arrival", "dispatch", "prefix_match", "page_fetch",
-            "tier_get", "decompress", "kivi_dequantize", "kv_join",
+            "tier_get", "decompress", "kivi_dequantize",
             "insert", "tier_put", "kivi_quantize", "prefill", "admit",
             "lane_write", "admitted", "decode_tick",
             "first_token"} <= names
